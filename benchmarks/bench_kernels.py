@@ -5,8 +5,6 @@ TPU the kernel path is the fast one)."""
 
 from __future__ import annotations
 
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -16,29 +14,18 @@ from benchmarks.common import emit, measure_interleaved, time_call
 from repro.data.distributions import make_array
 from repro.kernels import batched, ops, ref
 
-# Row-backend A/B shapes: the serving buckets the engine's autotune gates
-# on (B requests × padded row length).  Smoke shrinks to a wiring check.
+# Row-backend A/B shapes: serving buckets (B requests × padded row
+# length).  Smoke shrinks to a wiring check.
 ROWSORT_SHAPES = ((64, 1024), (64, 4096))
 ROWSORT_SMOKE_SHAPES = ((8, 256),)
-# A backend the autotune *selected* may not lose to the best alternative
-# by more than this factor in the same interleaved measurement.
-ROWSORT_SELECTED_SLACK = 1.25
 
 
 def _rowsort_ab(paper: bool) -> None:
     """Interleaved A/B of the segment-path row backends (DESIGN.md §8):
-    vmapped XLA ``jnp.sort`` vs the fused Pallas batched kernel (both
-    compare-exchange variants) on identical full-range int32 batches.
-
-    This is the measured ground the engine's ``choose_row_backend``
-    autotune stands on, so the same run re-judges the autotune itself: at
-    the gated (non-smoke) shapes, a backend the probe selects that then
-    loses the interleaved A/B by more than ``ROWSORT_SELECTED_SLACK``
-    fails the benchmark — a selected-but-slower autotune is a bug, not a
-    taste difference.
+    vmapped XLA ``jnp.sort`` (the engine's default) vs the fused Pallas
+    batched kernel (both compare-exchange variants) on identical
+    full-range int32 batches.
     """
-    from repro.core import engine as engine_mod
-
     interpret = ops._auto_interpret(None)
     shapes = ROWSORT_SMOKE_SHAPES if common.SMOKE else ROWSORT_SHAPES
     for B, L in shapes:
@@ -66,24 +53,6 @@ def _rowsort_ab(paper: bool) -> None:
                 f"kernels/rowsort_{name}/B{B}xL{L}",
                 m.median_s * 1e6,
                 f"vs_vmap={ratio:.2f};iqr_us={m.iqr_s * 1e6:.0f}",
-            )
-        if common.SMOKE or os.environ.get("REPRO_ROW_BACKEND", "").strip():
-            continue  # forced/smoke runs don't judge the autotune
-        backend, detail = engine_mod.choose_row_backend(
-            L, np.int32, batch_hint=B
-        )
-        chosen = meas[backend].median_s
-        best = min(m.median_s for m in meas.values())
-        emit(
-            f"kernels/rowsort_autotune/B{B}xL{L}",
-            chosen * 1e6,
-            f"picked={backend};vs_best={chosen / best:.2f}",
-        )
-        if chosen > best * ROWSORT_SELECTED_SLACK:
-            raise RuntimeError(
-                f"row-backend autotune picked {backend!r} at B{B}xL{L} but "
-                f"the interleaved A/B has it {chosen / best:.2f}x off the "
-                f"best backend (slack {ROWSORT_SELECTED_SLACK}); {detail}"
             )
 
 

@@ -40,6 +40,7 @@ from benchmarks import (
 )
 from benchmarks import common
 from benchmarks.common import DEFAULT_DTYPE, DTYPES
+from repro.compile_cache import enable_compile_cache
 
 SUITES = {
     "sequential": lambda a: bench_sequential.run(a.paper),  # Fig 6.1
@@ -138,6 +139,7 @@ def main() -> None:
         ap.error("--smoke and --paper are mutually exclusive")
     if args.smoke:
         common.set_smoke(True)
+    enable_compile_cache()
     print("name,us_per_call,derived")
     for name, fn in SUITES.items():
         if args.only and name != args.only:

@@ -1,76 +1,34 @@
-"""jax version-compatibility shims.
+"""Thin wrappers over the jax calls whose spelling the repo pins in one place.
 
-The framework targets the modern jax API (``jax.shard_map`` with
-``check_vma``, ``jax.make_mesh(..., axis_types=...)``); CI pins and
-hermetic containers may carry jax 0.4.x, where shard_map still lives in
-``jax.experimental`` with ``check_rep`` and meshes take no axis types.
-Every call site routes through these two wrappers so the version split
-lives in exactly one file.
+The framework targets jax 0.9: ``jax.shard_map`` with replication checking
+off and ``jax.make_mesh(..., axis_types=...)`` with Auto axes.  Every call
+site routes through these wrappers so the arguments are chosen once.
 """
 
 from __future__ import annotations
 
 import jax
 
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _CHECK_KW = {"check_vma": False}
-else:  # pragma: no cover - jax<=0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _CHECK_KW = {"check_rep": False}
-
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` with replication checking off, on any jax version."""
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **_CHECK_KW
+    """``jax.shard_map`` with replication checking off."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
     )
 
 
-def set_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh.
-
-    ``jax.set_mesh`` on modern jax; on 0.4.x a ``Mesh`` is itself a context
-    manager that pushes the thread-local physical mesh.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
 def get_ambient_mesh():
-    """The mesh installed by :func:`set_mesh`, or ``None`` outside one."""
-    if hasattr(jax.sharding, "get_abstract_mesh"):
-        m = jax.sharding.get_abstract_mesh()
-        return m if (m is not None and m.shape) else None
-    from jax.interpreters import pxla  # pragma: no cover - jax<=0.4.x
-
-    m = pxla.thread_resources.env.physical_mesh
-    return None if m.empty else m
-
-
-def axis_size(axis_name):
-    """``jax.lax.axis_size`` (jax>=0.6); 0.4.x spells it psum(1, axis)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)  # pragma: no cover - jax<=0.4.x
-
-
-def cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` as a flat dict on any jax version
-    (0.4.x returns a list with one dict per computation)."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        return ca[0] if ca else {}
-    return ca
+    """The mesh installed by ``jax.set_mesh``, or ``None`` outside one."""
+    m = jax.sharding.get_abstract_mesh()
+    return m if (m is not None and m.shape) else None
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None):
-    """``jax.make_mesh`` with Auto axis types where the version wants them."""
+    """``jax.make_mesh`` with Auto axis types."""
     kw = {} if devices is None else {"devices": devices}
-    if hasattr(jax.sharding, "AxisType"):
-        kw["axis_types"] = tuple(
-            jax.sharding.AxisType.Auto for _ in axis_names
-        )
-    return jax.make_mesh(axis_shapes, axis_names, **kw)
+    return jax.make_mesh(
+        axis_shapes,
+        axis_names,
+        axis_types=tuple(jax.sharding.AxisType.Auto for _ in axis_names),
+        **kw,
+    )

@@ -41,21 +41,22 @@ into ONE padded ``(B, Lbucket)`` vmapped device call (worst-row stats and
 capacity measured in one vectorized pass — the device-side foundation of
 the ``repro.serve.sortd`` micro-batching service, DESIGN.md §8);
 ``sort_many`` is its list-of-arrays wrapper; ``sort_pairs`` is the
-key/payload sort (bitonic pair kernel) behind
+key/payload sort (multi-operand ``lax.sort``) behind
 ``repro.serve.engine.ServeEngine``'s length-ordering hot path.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 import os
-import time
 from typing import Callable, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.core import partition, workloads
 from repro.core.ohhc_sort import ohhc_sort_host
@@ -90,101 +91,34 @@ _BACKEND_METHODS = {
 # capacity, no overflow (the complement of the bucket-path methods).
 BITONIC_METHODS = tuple(_BACKEND_METHODS.values())
 
-# One measured head-to-head per (row bucket, dtype, probe batch) per
-# process — shared across engines so a fleet of workers probes once, like
-# a jit cache.
-_ROW_BACKEND_CACHE: dict[tuple[int, str, int], tuple[str, str]] = {}
-
-# The probe batch is bucketed to the serving batch (pow2, clamped) because
-# relative backend cost is batch-dependent: the interpreted Pallas grid
-# walks rows sequentially while the vmapped XLA sort amortizes across the
-# whole batch, so a B=8 probe mispredicts a B=64 serve.
-_PROBE_BATCH_MIN, _PROBE_BATCH_MAX = 8, 64
-
-
-def _probe_batch_for(batch_hint: int) -> int:
-    b = max(int(batch_hint), 1)
-    return min(max(1 << (b - 1).bit_length(), _PROBE_BATCH_MIN), _PROBE_BATCH_MAX)
+# Largest input the sim path takes by default; ``host_threshold`` is one
+# past it.  n ≤ 2^24 covers the paper's 10–60 MB int32 arrays and pads to
+# at most the 2^24 shape bucket, whose int32 ``paper`` executable a
+# described-v5e compile sizes (memory_analysis) at a 64 MiB argument plus
+# 5.5 GB of temporaries — the O(n·P) rank matrix dominates — about a third
+# of one chip's 16 GB of HBM.  The 2^25 bucket needs 11.1 GB; whether the
+# device still pays off there is for a chip measurement to say.  Larger
+# inputs take the exact numpy host path.
+SIM_MAX_N = 1 << 24
+HOST_THRESHOLD = SIM_MAX_N + 1
 
 
-def choose_row_backend(
-    padded_n: int,
-    dtype,
-    *,
-    local_sort: Callable | None = None,
-    batch_hint: int = 8,
-    probe_batch: "int | None" = None,
-    repeats: int = 3,
-) -> tuple[str, str]:
-    """Autotuned row-sort backend for bitonic segment rows: measured
-    head-to-head of the vmapped XLA path vs the fused Pallas kernel
-    (both variants on integer keys), at plan time, on this host's actual
-    execution mode (interpret on CPU, compiled Mosaic on TPU).
+def choose_row_backend() -> tuple[str, str]:
+    """Row-sort backend for bitonic segment rows: ``vmap`` (the vmapped XLA
+    ``jnp.sort``) on every backend, with no probe.
 
-    The probe runs at the serving batch size (``batch_hint`` bucketed by
-    :func:`_probe_batch_for`; ``probe_batch`` overrides it exactly) —
-    backend ranking flips with batch, so probing a fixed tiny batch would
-    select a backend the real batch then loses with.
-
-    Returns ``(backend, detail)`` where ``detail`` is the human-readable
-    probe record that lands in ``SortPlan.reason``.  Cached per
-    ``(padded_n, dtype, probe batch)`` for the process; ``REPRO_ROW_BACKEND``
-    forces a backend (``vmap`` / ``pallas`` / ``pallas2op``) and skips the
-    probe — the deterministic knob tests, benchmarks and operators use.
+    The fused Pallas row kernels (``pallas`` / ``pallas2op``) do not compile
+    for TPU v5e, so they run only when a caller asks for them:
+    ``REPRO_ROW_BACKEND`` forces a backend, or an explicit plan names its
+    method.  A forced kernel the chip's compiler refuses raises there.
+    Returns ``(backend, detail)``; ``detail`` lands in ``SortPlan.reason``.
     """
     forced = os.environ.get("REPRO_ROW_BACKEND", "").strip().lower()
-    if forced:
-        if forced not in ROW_BACKENDS:
-            raise ValueError(
-                f"REPRO_ROW_BACKEND={forced!r} not in {ROW_BACKENDS}"
-            )
-        return forced, f"row_backend={forced} (forced via REPRO_ROW_BACKEND)"
-    if probe_batch is None:
-        probe_batch = _probe_batch_for(batch_hint)
-    np_dtype = np.dtype(dtype)
-    key = (padded_n, str(np_dtype), probe_batch)
-    hit = _ROW_BACKEND_CACHE.get(key)
-    if hit is not None:
-        return hit
-    interpret = ops._auto_interpret(None)
-    rng = np.random.default_rng(padded_n)
-    if np.issubdtype(np_dtype, np.integer):
-        info = np.iinfo(np_dtype)
-        x = rng.integers(
-            info.min, info.max, (probe_batch, padded_n), dtype=np_dtype
-        )
-    else:
-        x = rng.normal(size=(probe_batch, padded_n)).astype(np_dtype)
-    xj = jnp.asarray(x)
-    lens = jnp.full((probe_batch,), padded_n, jnp.int32)
-    row_sort = local_sort if local_sort is not None else jnp.sort
-    candidates: dict[str, Callable] = {
-        "vmap": jax.jit(jax.vmap(row_sort)),
-        "pallas": lambda a: batched_kernels.batched_row_sort(
-            a, lens, method="bitonic", interpret=interpret
-        ),
-    }
-    if np.issubdtype(np_dtype, np.integer):
-        candidates["pallas2op"] = lambda a: batched_kernels.batched_row_sort(
-            a, lens, method="bitonic2op", interpret=interpret
-        )
-    timings: dict[str, float] = {}
-    for name, fn in candidates.items():
-        fn(xj).block_until_ready()  # warm: trace + compile outside the clock
-        best = math.inf
-        for _ in range(repeats):
-            t0 = time.perf_counter()
-            fn(xj).block_until_ready()
-            best = min(best, time.perf_counter() - t0)
-        timings[name] = best
-    backend = min(timings, key=timings.get)  # type: ignore[arg-type]
-    detail = "row_backend=%s (autotuned @B%d: %s)" % (
-        backend,
-        probe_batch,
-        ", ".join(f"{k} {v * 1e3:.2f}ms" for k, v in timings.items()),
-    )
-    _ROW_BACKEND_CACHE[key] = (backend, detail)
-    return backend, detail
+    if not forced:
+        return "vmap", "row_backend=vmap (default)"
+    if forced not in ROW_BACKENDS:
+        raise ValueError(f"REPRO_ROW_BACKEND={forced!r} not in {ROW_BACKENDS}")
+    return forced, f"row_backend={forced} (forced via REPRO_ROW_BACKEND)"
 
 
 def x64_enabled() -> bool:
@@ -474,8 +408,7 @@ def choose_batch_plan(
       the kernel (:data:`ROW_BACKENDS`): ``vmap`` → ``bitonic`` (vmapped
       XLA sort, the default), ``pallas`` → ``bitonic_pallas`` (the fused
       batched Pallas kernel), ``pallas2op`` → ``bitonic2op`` (its NICE
-      2-op stage); the engine feeds this from the
-      :func:`choose_row_backend` measured head-to-head;
+      2-op stage); the engine feeds this from :func:`choose_row_backend`;
     * longer rows run the paper's bucket path: ``sampled`` splitters when
       the worst row is skewed but not duplicate-dominated (quantile
       splitters cannot split one repeated value), else the equal-width
@@ -508,7 +441,7 @@ def choose_plan(
     *,
     mesh_devices: int = 1,
     mesh_axes: Sequence[str] = (),
-    host_threshold: int = 1 << 20,
+    host_threshold: int = HOST_THRESHOLD,
     margin: float = 1.25,
 ) -> SortPlan:
     """Stats × topology → (path, method, capacity).  Pure and unit-testable."""
@@ -709,9 +642,10 @@ class SortEngine:
                      full, 36 processors).
     mesh/axis_names: when given (and the mesh has >1 device), large requests
                      dispatch to ``dist_sort`` over the mesh.
-    host_threshold:  sizes ≥ this go to the exact numpy path.
+    host_threshold:  sizes ≥ this go to the exact numpy path (default
+                     :data:`HOST_THRESHOLD`, one past :data:`SIM_MAX_N`).
     local_sort:      per-bucket sorter for the sim path (default
-                     ``jnp.sort``; pass ``ops.make_local_sort()`` on TPU).
+                     ``jnp.sort``, XLA's sort on every backend).
     fault_scenario:  optional ``net.faults.FaultScenario`` the engine serves
                      under (DESIGN.md §11): plans re-price the gather over
                      the degraded topology (``SortPlan.fault_slowdown``) and
@@ -726,7 +660,7 @@ class SortEngine:
         *,
         mesh=None,
         axis_names: Sequence[str] = ("data",),
-        host_threshold: int = 1 << 20,
+        host_threshold: int = HOST_THRESHOLD,
         sample_size: int = 2048,
         margin: float = 1.25,
         local_sort: Callable[[jax.Array], jax.Array] | None = None,
@@ -1047,10 +981,7 @@ class SortEngine:
             return choose_batch_plan(
                 stats, self.topo.total_procs, padded_n, margin=self.margin
             )
-        backend, detail = choose_row_backend(
-            padded_n, keys.dtype, local_sort=self.local_sort,
-            batch_hint=int(lens.size),
-        )
+        backend, detail = choose_row_backend()
         plan = choose_batch_plan(
             None, self.topo.total_procs, padded_n,
             margin=self.margin, row_backend=backend,
@@ -1172,12 +1103,8 @@ class SortEngine:
         if plan is None:
             if padded_n <= SEGMENT_BITONIC_MAX:
                 # the bitonic row kernels need no capacity → no stats pass;
-                # the backend (vmap vs fused Pallas) comes from the cached
-                # measured head-to-head (or REPRO_ROW_BACKEND)
-                backend, detail = choose_row_backend(
-                    padded_n, keys.dtype, local_sort=self.local_sort,
-                    batch_hint=B_pad,
-                )
+                # the backend is vmap unless REPRO_ROW_BACKEND forces one
+                backend, detail = choose_row_backend()
                 plan = choose_batch_plan(
                     None, self.topo.total_procs, padded_n,
                     margin=self.margin, row_backend=backend,
@@ -1248,18 +1175,19 @@ class SortEngine:
         return self.sort_segments(flat, lens)
 
     def sort_pairs(self, keys, vals):
-        """Key/payload sort — flat arrays on the pair kernel, pytrees via
-        a permutation gather (DESIGN.md §12).
+        """Key/payload sort — flat arrays on one multi-operand XLA sort,
+        pytrees via a permutation gather (DESIGN.md §12).
 
-        A single flat 1-D payload array takes the legacy tagged bitonic
-        pair kernel directly (warm shape cache, returns jax arrays) — the
-        serving hot path (length-ordering a request batch) calls this with
-        a different batch size every tick, and pow2 bucketing makes all of
-        them share a handful of executables instead of one per size.
+        A single flat 1-D payload array is sorted by ``lax.sort`` over
+        (key, validity tag, payload) directly (warm shape cache, returns
+        jax arrays) — the serving hot path (length-ordering a request
+        batch) calls this with a different batch size every tick, and pow2
+        bucketing makes all of them share a handful of executables instead
+        of one per size.
 
         Any other payload pytree (nested dicts/tuples, mixed dtypes,
-        multi-dim leaves) rides :meth:`argsort_keys`: the same tagged pair
-        kernel sorts ``(key, index)`` once, then every flattened leaf is
+        multi-dim leaves) rides :meth:`argsort_keys`: the same flat pairs
+        sort orders ``(key, index)`` once, then every flattened leaf is
         gathered by the permutation on the host — byte-exact for every
         leaf dtype (64-bit leaves survive without jax x64).  Returns
         ``(sorted_keys, same-structure payload)`` as numpy.
@@ -1273,56 +1201,68 @@ class SortEngine:
             return self._sort_pairs_flat(keys, leaves[0])
         return self._sort_pairs_tree(keys, leaves, treedef)
 
+    def _get_pairs_fn(self, n_pad: int, key_dtype, val_dtype):
+        key = ("pairs", n_pad, str(key_dtype), str(val_dtype))
+        fn = self._fn_cache.get(key)
+        if fn is None:
+            def traced(k, v, n_valid):
+                self.trace_count += 1  # runs at trace time only
+                # Lexicographic (key, validity tag) order: pad slots tag 1,
+                # so real keys equal to the dtype-max pad sentinel keep
+                # their payloads ahead of the zero-payload pad tail (the
+                # sentinel-tie hazard).  n_valid is traced, so every length
+                # in the bucket shares one executable.
+                tags = (jnp.arange(n_pad) >= n_valid).astype(jnp.int32)
+                ks, _, vs = jax.lax.sort((k, tags, v), num_keys=2)
+                return ks, vs
+
+            fn = jax.jit(traced)
+            self._fn_cache[key] = fn
+        return fn
+
     def _sort_pairs_flat(self, keys, vals):
-        """The legacy flat path: one payload array through the tagged
-        bitonic pair kernel (sentinel-tie safe, n_valid traced)."""
+        """The flat path: one payload array through a multi-operand XLA
+        sort (sentinel-tie safe, n_valid traced)."""
         keys = jnp.asarray(keys).ravel()
         vals = jnp.asarray(vals).ravel()
         n = keys.shape[0]
         if n <= 1:
             return keys, vals
         n_pad = ops.bucketed_length(n)
-        key = ("pairs", n_pad, str(keys.dtype), str(vals.dtype))
-        fn = self._fn_cache.get(key)
-        if fn is None:
-            def traced(k, v, n_valid):
-                self.trace_count += 1
-                # n_valid is traced: the pre-pad below makes every length in
-                # the bucket look like n_pad to the kernel, so the validity
-                # boundary must ride along or pad zeros could displace real
-                # payloads on dtype-max key ties (the sentinel-tie hazard).
-                return ops.local_sort_pairs(k, v, n_valid=n_valid)
-
-            fn = jax.jit(traced)
-            self._fn_cache[key] = fn
+        fn = self._get_pairs_fn(n_pad, keys.dtype, vals.dtype)
         fill = _sim_fill(keys.dtype)
         kp = jnp.concatenate([keys, jnp.full((n_pad - n,), fill, keys.dtype)])
         vp = jnp.concatenate([vals, jnp.zeros((n_pad - n,), vals.dtype)])
         ks, vs = fn(kp, vp, n)
+        self.last_report = {
+            "plan": SortPlan(
+                "sim", "pairs", None, n_pad,
+                f"pairs: lax.sort over (key, validity tag, payload), n={n}",
+            ),
+            "n": n, "overflow_retries": 0, "counts_sum": n,
+        }
         return ks[:n], vs[:n]
 
     def argsort_keys(self, keys) -> tuple[np.ndarray, np.ndarray]:
         """``(sorted_keys, permutation)`` with ``sorted_keys == keys[perm]``.
 
-        The permutation comes from the tagged pair kernel sorting
+        The permutation comes from the flat pairs sort of
         ``(key, arange)`` — the sentinel-tie-safe path, so keys equal to
-        the dtype max keep their payload.  64-bit keys without jax x64 and
-        arrays past the kernel's ``MAX_TILE`` take the host stable argsort
-        (the same exactness rule as ``choose_plan``'s host fallback).
+        the dtype max keep their payload.  64-bit keys without jax x64
+        take the host stable argsort (the same exactness rule as
+        ``choose_plan``'s host fallback).
         """
         keys_np = np.asarray(keys).ravel()
         n = keys_np.size
         if n <= 1:
             return keys_np.copy(), np.arange(n, dtype=np.int64)
-        if (keys_np.dtype.itemsize == 8 and not x64_enabled()) or (
-            ops.bucketed_length(n) > ops.MAX_TILE
-        ):
+        if keys_np.dtype.itemsize == 8 and not x64_enabled():
             perm = np.argsort(keys_np, kind="stable")
             self.last_report = {
                 "plan": SortPlan(
                     "host", "pairs", None, None,
                     f"argsort: {keys_np.dtype} n={n} host stable argsort "
-                    "(x64/tile exactness rule)",
+                    "(x64 exactness rule)",
                 ),
                 "n": n, "overflow_retries": 0, "counts_sum": n,
             }
@@ -1331,7 +1271,7 @@ class SortEngine:
         self.last_report = {
             "plan": SortPlan(
                 "sim", "pairs", None, ops.bucketed_length(n),
-                f"argsort: tagged pair kernel over (key, arange), n={n}",
+                f"argsort: lax.sort over (key, validity tag, arange), n={n}",
             ),
             "n": n, "overflow_retries": 0, "counts_sum": n,
         }
@@ -1605,16 +1545,27 @@ class SortEngine:
             x_np = np.concatenate([x_np, np.full(pad, fill, x_np.dtype)])
         f_hat = stats.f_max_sampled if plan.method != "paper" else stats.f_max_paper
         cf = max(2.0, self.margin * f_hat * num_shards * 2.0)
-        xj = jnp.asarray(x_np)
+        # Each device receives only its own shard (never the whole array on
+        # device 0).
+        xj = jax.device_put(
+            x_np, NamedSharding(self.mesh, PartitionSpec(self.axis_names))
+        )
         retries = 0
         while True:
-            vals, counts = dist_sort(
-                xj,
-                mesh=self.mesh,
-                axis_names=self.axis_names,
-                method=plan.method,
-                capacity_factor=cf,
-            )
+            key = ("dist", x_np.shape, str(x_np.dtype), plan.method, cf)
+            fn = self._fn_cache.get(key)
+            if fn is None:
+                fn = jax.jit(
+                    functools.partial(
+                        dist_sort,
+                        mesh=self.mesh,
+                        axis_names=self.axis_names,
+                        method=plan.method,
+                        capacity_factor=cf,
+                    )
+                )
+                self._fn_cache[key] = fn
+            vals, counts = fn(xj)
             counts = np.asarray(counts).ravel()
             if int(counts.sum()) == x_np.size:
                 break

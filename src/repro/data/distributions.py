@@ -77,9 +77,10 @@ def make_array(dist: str, n: int, seed: int = 0, dtype=np.int32) -> np.ndarray:
         center = vmax // 2
         sigma = max(1.0, 1e5 * (vmax / np.iinfo(np.int32).max))
         x = rng.normal(center, sigma, n).astype(np.int64)
-        k = max(n // 1000, 2)
-        idx = rng.integers(0, n, k)
-        x[idx] = rng.integers(0, vmax, k, dtype=np.int64)
+        if n:  # an empty array has no positions to draw the tail into
+            k = max(n // 1000, 2)
+            idx = rng.integers(0, n, k)
+            x[idx] = rng.integers(0, vmax, k, dtype=np.int64)
     else:
         raise ValueError(f"unknown distribution {dist!r}")
     return np.clip(x, 0, vmax).astype(dt)

@@ -96,9 +96,7 @@ def main():
         if shown >= args.top:
             break
 
-    from repro import compat
-
-    ca = compat.cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     print(f"\nflops={ca.get('flops',0):.3e}  bytes={ca.get('bytes accessed',0):.3e}")
     mem = compiled.memory_analysis()
     print(f"temp={mem.temp_size_in_bytes/1e9:.2f}GB arg={mem.argument_size_in_bytes/1e9:.2f}GB")

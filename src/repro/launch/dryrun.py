@@ -22,7 +22,6 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
-from repro import compat  # noqa: E402
 
 from repro.configs import registry  # noqa: E402
 from repro.configs.base import SHAPES, ModelConfig, RunConfig, ShapeConfig  # noqa: E402
@@ -82,7 +81,7 @@ def build_lowered(cfg, shape, mesh, run, *, cache_len=None):
     params_shape = jax.eval_shape(lambda: model_api.init(key, cfg))
     pspecs = SH.sanitize_specs(pspecs_l, params_shape, mesh)
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             from repro.train.train_step import init_train_state, make_train_step
 
@@ -154,7 +153,7 @@ def _measure(cfg, shape, mesh, run, *, pod_block):
     roofline.analysis.collective_bytes."""
     lowered, _ = build_lowered(cfg, shape, mesh, run)
     compiled = lowered.compile()
-    ca = compat.cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     halve = None
     if run.master_weights:
         from repro.roofline.analysis import param_shape_set

@@ -144,8 +144,8 @@ def _local_sort_setup(n: int):
 def _rowsort_setup(backend: str, B: int, L: int):
     """One segment-path row backend on a fixed full-range int32 batch:
     ``vmap`` jits the vmapped XLA sort, the pallas backends call the fused
-    batched kernel (``repro.kernels.batched``) directly — same candidates
-    the engine's ``choose_row_backend`` autotune races."""
+    batched kernel (``repro.kernels.batched``) directly — the backends
+    ``choose_row_backend`` can select."""
 
     def setup():
         import jax
@@ -190,8 +190,7 @@ def kernels_cases(*, smoke: bool = True) -> "list[PerfCase]":
             workload=_sort_workload(4096, 4),
             **wide,
         ),
-        # The row-backend A/B the engine's autotune stands on, persisted as
-        # paired baseline rows: the committed raw_s ratio documents which
+        # The row-backend A/B, persisted as paired baseline rows: the committed raw_s ratio documents which
         # backend wins the B64xL1024 serving bucket on this host, and
         # perfguard re-judges each side on every gate run
         # (benchmarks/bench_kernels.py runs the same pair interleaved).

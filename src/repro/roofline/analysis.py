@@ -206,9 +206,7 @@ def roofline_from_compiled(
     model_flops: float | None = None,
 ) -> dict:
     """The §Roofline record for one (arch × shape × mesh) cell."""
-    from repro import compat
-
-    ca = compat.cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     flops_dev = float(ca.get("flops", 0.0))
     bytes_dev = float(ca.get("bytes accessed", 0.0))
     hlo = compiled.as_text()

@@ -18,8 +18,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro import compat
-
 
 def int8_psum(x: jax.Array, axis_name: str) -> jax.Array:
     """Quantised psum (inside shard_map).  int32 accumulation, f32 scale."""
@@ -39,7 +37,7 @@ def hierarchical_psum(x: jax.Array, *, fast_axis: str, slow_axis: str) -> jax.Ar
     Equivalent to ``psum(x, (fast, slow))`` but the slow tier carries
     1/|fast| of the bytes — the paper's optical-tier economy.
     """
-    n_fast = compat.axis_size(fast_axis)
+    n_fast = jax.lax.axis_size(fast_axis)
     lead = x.shape[0]
     if lead % n_fast:
         # fall back for indivisible leading dims

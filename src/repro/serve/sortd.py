@@ -179,8 +179,8 @@ class _BucketStats:
         self.valid_cells = 0
         self.lat_s = collections.deque(maxlen=window)
         # flush count per executed plan method (e.g. bitonic vs
-        # bitonic_pallas vs bitonic2op) — names the kernel the engine's
-        # row-backend autotune actually ran for this bucket's traffic
+        # bitonic_pallas vs bitonic2op) — names the kernel the engine
+        # actually ran for this bucket's traffic
         self.methods: dict[str, int] = {}
 
 

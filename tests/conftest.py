@@ -1,17 +1,8 @@
 # NOTE: no XLA_FLAGS here on purpose — smoke tests and benches must see the
 # real single CPU device.  Multi-device tests spawn subprocesses that set
 # --xla_force_host_platform_device_count themselves (see test_dist_sort.py).
-import os
-
 import numpy as np
 import pytest
-
-# Pin the segmented row-sort backend for the suite: the autotune probe is a
-# *timed* head-to-head, so near-tie sizes could flip vmap↔pallas run to run
-# and every first-touch (padded_n, dtype) would pay a probe's jit traces.
-# Tests that exercise the pallas routing or the autotune itself override
-# this explicitly (test_engine.py, test_kernels_batched.py).
-os.environ.setdefault("REPRO_ROW_BACKEND", "vmap")
 
 
 @pytest.fixture

@@ -34,7 +34,7 @@ pspecs = SH.sanitize_specs(api.param_specs(cfg, rules, 2), jax.eval_shape(lambda
 sspecs = {"params": pspecs, "opt": {"m": pspecs, "v": pspecs, "count": P()}, "step": P()}
 in_specs = registry.input_specs(cfg, shape)
 bspecs = SH.sanitize_specs(SH.batch_specs(cfg, shape, rules), in_specs, mesh)
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     step = make_train_step(cfg, run, api, rules)
     jitted = jax.jit(step, in_shardings=(SH.named(sspecs,mesh), SH.named(bspecs,mesh)),
                      out_shardings=(SH.named(sspecs,mesh), None), donate_argnums=(0,))

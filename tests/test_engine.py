@@ -65,7 +65,12 @@ def test_policy_duplicate_heavy_forces_paper():
 
 
 def test_policy_huge_goes_host():
-    p = choose_plan(mk_stats(n=1 << 21), TOPO)
+    from repro.core.engine import SIM_MAX_N
+
+    # the paper's largest arrays (n ≤ 2^24) stay on the device; one past
+    # the bound pads to a bucket one chip cannot hold and goes to the host
+    assert choose_plan(mk_stats(n=SIM_MAX_N), TOPO).path == "sim"
+    p = choose_plan(mk_stats(n=SIM_MAX_N + 1), TOPO)
     assert p.path == "host"
 
 
@@ -403,31 +408,23 @@ def test_batch_plan_row_backend_mapping():
 
 def test_choose_row_backend_env_and_probe(monkeypatch):
     from repro.core import ROW_BACKENDS, choose_row_backend
-    from repro.core import engine as engine_mod
 
-    # env override wins and skips the probe
-    monkeypatch.setenv("REPRO_ROW_BACKEND", "pallas2op")
-    backend, detail = choose_row_backend(256, np.int32)
-    assert backend == "pallas2op" and "forced" in detail
+    # env override forces a backend, including the Pallas kernels
+    for forced in ROW_BACKENDS:
+        monkeypatch.setenv("REPRO_ROW_BACKEND", forced)
+        backend, detail = choose_row_backend()
+        assert backend == forced and "forced" in detail
     monkeypatch.setenv("REPRO_ROW_BACKEND", "cuda")
     with pytest.raises(ValueError, match="REPRO_ROW_BACKEND"):
-        choose_row_backend(256, np.int32)
-    # the measured head-to-head: runs all candidates, caches per
-    # (padded_n, dtype, probe batch), returns a record for SortPlan.reason
-    monkeypatch.delenv("REPRO_ROW_BACKEND")
-    monkeypatch.setattr(engine_mod, "_ROW_BACKEND_CACHE", {})
-    backend, detail = choose_row_backend(128, np.int32, probe_batch=4, repeats=1)
-    assert backend in ROW_BACKENDS
-    assert "autotuned" in detail and "vmap" in detail and "pallas" in detail
-    assert engine_mod._ROW_BACKEND_CACHE[(128, "int32", 4)] == (backend, detail)
-    # the probe batch buckets to the serving batch (pow2, clamped): backend
-    # ranking flips with batch size, so the probe must match the serve
-    assert engine_mod._probe_batch_for(1) == 8
-    assert engine_mod._probe_batch_for(24) == 32
-    assert engine_mod._probe_batch_for(500) == 64
-    # float keys: no 2-op candidate (the modular max identity is int-only)
-    b2, d2 = choose_row_backend(128, np.float32, probe_batch=4, repeats=1)
-    assert b2 in ("vmap", "pallas") and "pallas2op" not in d2
+        choose_row_backend()
+    # the default is vmap (XLA's sort) with no probe: nothing runs, nothing
+    # is compiled, and the Pallas kernels are never a default
+    monkeypatch.delenv("REPRO_ROW_BACKEND", raising=False)
+    assert choose_row_backend() == ("vmap", "row_backend=vmap (default)")
+    eng = SortEngine(TOPO)
+    plan = eng.plan_segments(np.arange(300, dtype=np.int32), [100, 200])
+    assert plan.method == "bitonic" and eng.trace_count == 0
+    assert "row_backend=vmap (default)" in plan.reason
 
 
 def test_sort_segments_pallas_backends(monkeypatch):
