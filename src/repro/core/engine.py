@@ -102,6 +102,29 @@ BITONIC_METHODS = tuple(_BACKEND_METHODS.values())
 SIM_MAX_N = 1 << 24
 HOST_THRESHOLD = SIM_MAX_N + 1
 
+# Profiler spans of ``SortEngine.sort`` (DESIGN.md §4).
+SPAN_SORT = "sort_engine.sort"
+SPAN_PLAN = "sort_engine.plan"
+SPAN_PAD = "sort_engine.pad"
+SPAN_H2D = "sort_engine.h2d"
+SPAN_EXECUTE = "sort_engine.execute"
+SPAN_D2H = "sort_engine.d2h"
+SPAN_UNPACK = "sort_engine.unpack"
+SPAN_HOST_SORT = "sort_engine.host_sort"
+"""``jax.profiler.TraceAnnotation`` names, plain strings with no
+``#key=value`` arguments.  ``SPAN_SORT`` covers the whole of ``sort`` on
+every path; the stages nest inside it on the calling thread:
+``SPAN_PLAN`` (stats, plan, fault ladder), ``SPAN_PAD`` (the sim pad
+buffer, the dist shard-divisibility pad), ``SPAN_H2D``, one
+``SPAN_EXECUTE`` per attempt (dispatch plus the counts sync that waits
+for it, so an overflow retry shows as a second span), ``SPAN_D2H``,
+``SPAN_UNPACK`` (dist) and ``SPAN_HOST_SORT`` (host path).  They record
+only while a profiler runs, on its clock.  No span adds a sync: each
+measures what the host spends in its stage, and the trace's device lines
+show the rest.  Every executable the engine builds has a stable module
+name (``jit_sim_sort``, ``jit_row_sort``, ``jit_batch_row_sort``,
+``jit_pairs_sort``, ``jit_sim_topk``, ``jit_dist_sort``)."""
+
 
 def choose_row_backend() -> tuple[str, str]:
     """Row-sort backend for bitonic segment rows: ``vmap`` (the vmapped XLA
@@ -856,29 +879,31 @@ class SortEngine:
             interpret = ops._auto_interpret(None)
             kernel_method = "bitonic2op" if method == "bitonic2op" else "bitonic"
 
-            def traced_batch(x_pad, n_valid):
+            def batch_row_sort(x_pad, n_valid):
                 self.trace_count += 1  # runs at trace time only
                 out = batched_kernels.batched_row_sort(
                     x_pad, n_valid, method=kernel_method, interpret=interpret
                 )
                 return out, n_valid.astype(jnp.int32)[:, None]
 
-            fn = jax.jit(traced_batch)
+            fn = jax.jit(batch_row_sort)
             self._fn_cache[key] = fn
             return fn
 
-        def traced(x_pad, n_valid):
+        def row_sort(x_pad, n_valid):
+            # Direct sentinel-padded row sort (segmented batch rows,
+            # DESIGN.md §8): pad cells carry the dtype max, which sorts to
+            # the tail, so the valid prefix is exact even when real keys
+            # equal the sentinel.  Counts are the trivial per-row total —
+            # this kernel cannot overflow.
             self.trace_count += 1  # runs at trace time only
-            if method == "bitonic":
-                # Direct sentinel-padded row sort (segmented batch rows,
-                # DESIGN.md §8): pad cells carry the dtype max, which
-                # sorts to the tail, so the valid prefix is exact even
-                # when real keys equal the sentinel.  Counts are the
-                # trivial per-row total — this kernel cannot overflow.
-                return (
-                    self.local_sort(x_pad),
-                    jnp.reshape(n_valid.astype(jnp.int32), (1,)),
-                )
+            return (
+                self.local_sort(x_pad),
+                jnp.reshape(n_valid.astype(jnp.int32), (1,)),
+            )
+
+        def sim_sort(x_pad, n_valid):
+            self.trace_count += 1  # runs at trace time only
             return _sim_sort_padded(
                 x_pad,
                 n_valid,
@@ -889,6 +914,7 @@ class SortEngine:
                 local_sort=self.local_sort,
             )
 
+        traced = row_sort if method == "bitonic" else sim_sort
         fn = jax.jit(jax.vmap(traced) if batched else traced)
         self._fn_cache[key] = fn
         return fn
@@ -901,49 +927,58 @@ class SortEngine:
         repo, NaN poisons the min/max splitter computation (NaN also
         compares after the +inf pad fill, so such elements can vanish from
         the valid prefix).  Pre-filter NaNs before sorting float keys.
+
+        Under ``jax.profiler`` the call and its stages show as the
+        :data:`SPAN_SORT` spans.
         """
-        x_np = np.asarray(x).ravel()
-        n = x_np.size
-        if n <= 1:
-            self.last_report = {"plan": None, "n": n, "overflow_retries": 0}
-            return x_np.copy()
-        # Stats are only measured when something consumes them: planning
-        # (no explicit plan) or the dist path's capacity factor.  A forced
-        # sim/host plan skips the sample entirely.
-        stats = None
-        if plan is None:
-            stats = self.stats(x_np)
-            plan = self.plan(x_np, stats)  # fault ladder applied inside
-        else:
-            # Forced plans go through the same ladder: an impossible
-            # scenario rewrites even an explicit sim/dist plan onto the
-            # healthy host path — that override IS the degraded-serving
-            # contract (zero wrong answers, DESIGN.md §11).
-            plan = self._apply_fault(plan, n=n, itemsize=x_np.dtype.itemsize)
-        if plan.path == "host":
-            r = ohhc_sort_host(x_np, self.topo, method=plan.method)
-            self.last_report = {
-                "plan": plan, "n": n, "stats": stats, "overflow_retries": 0,
-                "counts_sum": int(r.bucket_sizes.sum()),
-                "counts": np.asarray(r.bucket_sizes),
-            }
-            return r.sorted_array
-        if plan.path == "dist":
-            return self._sort_dist(x_np, plan, stats)
-        return self._sort_sim(x_np, plan, stats)
+        with jax.profiler.TraceAnnotation(SPAN_SORT):
+            x_np = np.asarray(x).ravel()
+            n = x_np.size
+            if n <= 1:
+                self.last_report = {"plan": None, "n": n, "overflow_retries": 0}
+                return x_np.copy()
+            # Stats are only measured when something consumes them: planning
+            # (no explicit plan) or the dist path's capacity factor.  A forced
+            # sim/host plan skips the sample entirely.
+            stats = None
+            with jax.profiler.TraceAnnotation(SPAN_PLAN):
+                if plan is None:
+                    stats = self.stats(x_np)
+                    plan = self.plan(x_np, stats)  # fault ladder applied inside
+                else:
+                    # Forced plans go through the same ladder: an impossible
+                    # scenario rewrites even an explicit sim/dist plan onto the
+                    # healthy host path — that override IS the degraded-serving
+                    # contract (zero wrong answers, DESIGN.md §11).
+                    plan = self._apply_fault(plan, n=n, itemsize=x_np.dtype.itemsize)
+            if plan.path == "host":
+                with jax.profiler.TraceAnnotation(SPAN_HOST_SORT):
+                    r = ohhc_sort_host(x_np, self.topo, method=plan.method)
+                self.last_report = {
+                    "plan": plan, "n": n, "stats": stats, "overflow_retries": 0,
+                    "counts_sum": int(r.bucket_sizes.sum()),
+                    "counts": np.asarray(r.bucket_sizes),
+                }
+                return r.sorted_array
+            if plan.path == "dist":
+                return self._sort_dist(x_np, plan, stats)
+            return self._sort_sim(x_np, plan, stats)
 
     def _sort_sim(self, x_np: np.ndarray, plan: SortPlan, stats) -> np.ndarray:
         n = x_np.size
         padded_n = plan.padded_n or ops.bucketed_length(n)
         capacity = plan.capacity or partition.default_capacity(padded_n, self.topo.total_procs)
-        x_pad = np.zeros(padded_n, x_np.dtype)
-        x_pad[:n] = x_np
-        xj = jnp.asarray(x_pad)
+        with jax.profiler.TraceAnnotation(SPAN_PAD):
+            x_pad = np.zeros(padded_n, x_np.dtype)
+            x_pad[:n] = x_np
+        with jax.profiler.TraceAnnotation(SPAN_H2D):
+            xj = jnp.asarray(x_pad)
         retries = 0
         while True:
-            fn = self._get_sim_fn(padded_n, capacity, plan.method, x_np.dtype, False)
-            out, counts = fn(xj, n)
-            got = int(jnp.sum(counts))
+            with jax.profiler.TraceAnnotation(SPAN_EXECUTE):
+                fn = self._get_sim_fn(padded_n, capacity, plan.method, x_np.dtype, False)
+                out, counts = fn(xj, n)
+                got = int(jnp.sum(counts))
             if got == n:
                 break
             # Measured-model miss: escalate capacity (×2, cap at padded_n —
@@ -953,12 +988,14 @@ class SortEngine:
             capacity = min(padded_n, capacity * 2)
             capacity += (-capacity) % 8
             retries += 1
+        with jax.profiler.TraceAnnotation(SPAN_D2H):
+            out = np.asarray(out)[:n]
+            counts = np.asarray(counts)
         self.last_report = {
             "plan": plan, "n": n, "stats": stats, "capacity_used": capacity,
-            "counts_sum": got, "overflow_retries": retries,
-            "counts": np.asarray(counts),
+            "counts_sum": got, "overflow_retries": retries, "counts": counts,
         }
-        return np.asarray(out)[:n]
+        return out
 
     # --------------------------------------------------------------- batched
     def plan_segments(self, keys, seg_lens) -> SortPlan:
@@ -1205,7 +1242,7 @@ class SortEngine:
         key = ("pairs", n_pad, str(key_dtype), str(val_dtype))
         fn = self._fn_cache.get(key)
         if fn is None:
-            def traced(k, v, n_valid):
+            def pairs_sort(k, v, n_valid):
                 self.trace_count += 1  # runs at trace time only
                 # Lexicographic (key, validity tag) order: pad slots tag 1,
                 # so real keys equal to the dtype-max pad sentinel keep
@@ -1216,7 +1253,7 @@ class SortEngine:
                 ks, _, vs = jax.lax.sort((k, tags, v), num_keys=2)
                 return ks, vs
 
-            fn = jax.jit(traced)
+            fn = jax.jit(pairs_sort)
             self._fn_cache[key] = fn
         return fn
 
@@ -1456,14 +1493,14 @@ class SortEngine:
         key = ("topk", padded_n, capacity, keep, str(dtype))
         fn = self._fn_cache.get(key)
         if fn is None:
-            def traced(x_pad, n_valid):
+            def sim_topk(x_pad, n_valid):
                 self.trace_count += 1  # runs at trace time only
                 return _sim_topk_padded(
                     x_pad, n_valid, P=self.topo.total_procs, keep=keep,
                     capacity=capacity, local_sort=self.local_sort,
                 )
 
-            fn = jax.jit(traced)
+            fn = jax.jit(sim_topk)
             self._fn_cache[key] = fn
         return fn
 
@@ -1529,44 +1566,50 @@ class SortEngine:
         from repro.core.dist_sort import dist_sort
 
         if stats is None:
-            stats = self.stats(x_np)
+            with jax.profiler.TraceAnnotation(SPAN_PLAN):
+                stats = self.stats(x_np)
         sizes = dict(zip(self.mesh.axis_names, self.mesh.devices.shape))
         num_shards = 1
         for ax in self.axis_names:
             num_shards *= sizes[ax]
         n = x_np.size
         pad = (-n) % num_shards
-        if pad:
-            fill = (
-                np.iinfo(x_np.dtype).max
-                if np.issubdtype(x_np.dtype, np.integer)
-                else np.inf
-            )
-            x_np = np.concatenate([x_np, np.full(pad, fill, x_np.dtype)])
+        with jax.profiler.TraceAnnotation(SPAN_PAD):
+            if pad:
+                fill = (
+                    np.iinfo(x_np.dtype).max
+                    if np.issubdtype(x_np.dtype, np.integer)
+                    else np.inf
+                )
+                x_np = np.concatenate([x_np, np.full(pad, fill, x_np.dtype)])
         f_hat = stats.f_max_sampled if plan.method != "paper" else stats.f_max_paper
         cf = max(2.0, self.margin * f_hat * num_shards * 2.0)
         # Each device receives only its own shard (never the whole array on
         # device 0).
-        xj = jax.device_put(
-            x_np, NamedSharding(self.mesh, PartitionSpec(self.axis_names))
-        )
+        with jax.profiler.TraceAnnotation(SPAN_H2D):
+            xj = jax.device_put(
+                x_np, NamedSharding(self.mesh, PartitionSpec(self.axis_names))
+            )
         retries = 0
         while True:
-            key = ("dist", x_np.shape, str(x_np.dtype), plan.method, cf)
-            fn = self._fn_cache.get(key)
-            if fn is None:
-                fn = jax.jit(
-                    functools.partial(
+            with jax.profiler.TraceAnnotation(SPAN_EXECUTE):
+                key = ("dist", x_np.shape, str(x_np.dtype), plan.method, cf)
+                fn = self._fn_cache.get(key)
+                if fn is None:
+                    # update_wrapper names the module jit_dist_sort
+                    fn = jax.jit(functools.update_wrapper(
+                        functools.partial(
+                            dist_sort,
+                            mesh=self.mesh,
+                            axis_names=self.axis_names,
+                            method=plan.method,
+                            capacity_factor=cf,
+                        ),
                         dist_sort,
-                        mesh=self.mesh,
-                        axis_names=self.axis_names,
-                        method=plan.method,
-                        capacity_factor=cf,
-                    )
-                )
-                self._fn_cache[key] = fn
-            vals, counts = fn(xj)
-            counts = np.asarray(counts).ravel()
+                    ))
+                    self._fn_cache[key] = fn
+                vals, counts = fn(xj)
+                counts = np.asarray(counts).ravel()
             if int(counts.sum()) == x_np.size:
                 break
             # Overflow drops elements (dist_sort contract); escalate like
@@ -1576,11 +1619,13 @@ class SortEngine:
                 raise AssertionError("dist overflow at capacity_factor == shards")
             cf = min(float(num_shards), cf * 2.0)
             retries += 1
-        vals = np.asarray(vals)
-        shards = np.split(vals, counts.size)
-        out = np.concatenate(
-            [sh[: int(c)] for sh, c in zip(shards, counts)]
-        )
+        with jax.profiler.TraceAnnotation(SPAN_D2H):
+            vals = np.asarray(vals)
+        with jax.profiler.TraceAnnotation(SPAN_UNPACK):
+            shards = np.split(vals, counts.size)
+            out = np.concatenate(
+                [sh[: int(c)] for sh, c in zip(shards, counts)]
+            )
         self.last_report = {
             "plan": plan, "n": n, "stats": stats,
             # counts includes the shard-divisibility pad (max-sentinel
