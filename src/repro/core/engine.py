@@ -95,10 +95,10 @@ BITONIC_METHODS = tuple(_BACKEND_METHODS.values())
 # past it.  n ≤ 2^24 covers the paper's 10–60 MB int32 arrays and pads to
 # at most the 2^24 shape bucket, whose int32 ``paper`` executable a
 # described-v5e compile sizes (memory_analysis) at a 64 MiB argument plus
-# 5.5 GB of temporaries — the O(n·P) rank matrix dominates — about a third
-# of one chip's 16 GB of HBM.  The 2^25 bucket needs 11.1 GB; whether the
-# device still pays off there is for a chip measurement to say.  Larger
-# inputs take the exact numpy host path.
+# 0.30 GB of temporaries (the bucket-id sort's operands, the padded rows,
+# the unscatter buffer), under 2% of one chip's 16 GB of HBM.  The 2^25
+# bucket needs 0.60 GB; whether the device still pays off there is for a
+# chip measurement to say.  Larger inputs take the exact numpy host path.
 SIM_MAX_N = 1 << 24
 HOST_THRESHOLD = SIM_MAX_N + 1
 
@@ -424,8 +424,8 @@ def choose_batch_plan(
 
     * rows up to ``bitonic_max`` take a bitonic method — a direct
       sentinel-padded row sort with **no** value partitioning.  At serving
-      row sizes the P-way bucket machinery (O(L·P) rank matrix + scatter +
-      P per-bucket sorts) costs an order of magnitude more device time than
+      row sizes the P-way bucket machinery (a stable sort by bucket id,
+      row slices, P per-bucket sorts) costs more device time than
       sorting the row outright, needs no capacity, and is immune to value
       skew — the fused batch IS the parallelism.  ``row_backend`` selects
       the kernel (:data:`ROW_BACKENDS`): ``vmap`` → ``bitonic`` (vmapped

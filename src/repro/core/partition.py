@@ -14,8 +14,11 @@ beyond-paper fix is classic sample sort: take an oversampled random/strided
 sample, sort it, use its quantiles as splitters.  Bucket population is then
 balanced to within a provable factor regardless of the value distribution.
 
-Everything here is pure ``jnp`` and jit-safe; the Pallas kernel twins live
-in ``repro.kernels`` (bucket histogram/rank via one-hot MXU matmul).
+Everything here is pure ``jnp`` and jit-safe.  :func:`scatter_to_buckets`
+places elements with one stable sort by bucket id, with no O(n·P)
+intermediate; :func:`bucket_ranks` / :func:`bucket_counts` keep the one-hot
+formulation, for the MoE dispatch and as the reference of the Pallas
+kernel twin in ``repro.kernels`` (histogram/rank via MXU matmul).
 """
 
 from __future__ import annotations
@@ -207,21 +210,40 @@ def scatter_to_buckets(
     reflects what was actually stored — overflow is therefore detectable as
     ``counts.sum() < x.size`` (callers raise/retry; see dist_sort docs).
     ``fill_value`` defaults to the dtype max so padded tails sort to the end.
+
+    One stable sort by bucket id lays every bucket out contiguously in
+    order of appearance — the same stable ranks :func:`bucket_ranks`
+    computes, without its O(n·B) one-hot matrix.  The bucket edges are
+    B+1 binary searches of the sorted ids, and row ``b`` is the slice of
+    ``capacity`` elements from its edge, its tail past ``counts[b]`` filled.
     """
     x = jnp.asarray(x).ravel()
+    bucket_ids = jnp.asarray(bucket_ids).ravel()
     if fill_value is None:
         fill_value = (
             jnp.iinfo(x.dtype).max
             if jnp.issubdtype(x.dtype, jnp.integer)
             else jnp.inf
         )
-    ranks = bucket_ranks(bucket_ids, num_buckets)
-    counts = jnp.minimum(bucket_counts(bucket_ids, num_buckets), capacity)
-    keep = ranks < capacity
-    flat_idx = jnp.where(keep, bucket_ids * capacity + ranks, num_buckets * capacity)
-    out = jnp.full(num_buckets * capacity + 1, fill_value, x.dtype)
-    out = out.at[flat_idx].set(x)[:-1]
-    return out.reshape(num_buckets, capacity), counts
+    fill = jnp.full(capacity, fill_value, x.dtype)
+    sorted_ids, sorted_x = jax.lax.sort(
+        (bucket_ids, x), num_keys=1, is_stable=True
+    )
+    edges = jnp.searchsorted(
+        sorted_ids,
+        jnp.arange(num_buckets + 1, dtype=sorted_ids.dtype),
+        side="left",
+        method="scan",
+    )
+    counts = jnp.minimum(jnp.diff(edges), capacity).astype(jnp.int32)
+    # ``capacity`` fill slots past the end keep every slice in bounds
+    # (dynamic_slice would clamp its start instead).
+    padded = jnp.concatenate([sorted_x, fill])
+    rows = jax.vmap(lambda e: jax.lax.dynamic_slice(padded, (e,), (capacity,)))(
+        edges[:-1]
+    )
+    keep = jnp.arange(capacity)[None, :] < counts[:, None]
+    return jnp.where(keep, rows, fill), counts
 
 
 def unscatter(
@@ -230,16 +252,21 @@ def unscatter(
     """Concatenate bucket prefixes (bucket order) into a flat array of ``total``.
 
     Because buckets are range-partitioned and individually sorted, the
-    result is globally sorted — §3.1's merge-free gather.
+    result is globally sorted — §3.1's merge-free gather.  Each row is
+    written whole at its exclusive prefix offset, in bucket order, so row
+    ``b + 1`` overwrites row ``b``'s tail past ``counts[b]``; slots at or
+    past ``sum(counts)`` read 0.
     """
     num_buckets, capacity = buckets.shape
     offsets = jnp.cumsum(counts) - counts  # exclusive prefix
-    pos_in_bucket = jnp.arange(capacity)[None, :]
-    valid = pos_in_bucket < counts[:, None]
-    dest = jnp.where(valid, offsets[:, None] + pos_in_bucket, total)
-    out = jnp.zeros(total + 1, buckets.dtype)
-    out = out.at[dest.ravel()].set(buckets.ravel())
-    return out[:total]
+
+    def put(b, out):
+        return jax.lax.dynamic_update_slice(out, buckets[b], (offsets[b],))
+
+    out = jax.lax.fori_loop(
+        0, num_buckets, put, jnp.zeros(total + capacity, buckets.dtype)
+    )[:total]
+    return jnp.where(jnp.arange(total) < jnp.sum(counts), out, 0)
 
 
 # Exact host-side twin of the engine's integer equal-width rule — lives in
