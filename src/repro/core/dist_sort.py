@@ -100,6 +100,14 @@ def _finalize(recv, recv_counts, local_sort):
     return merged, jnp.sum(recv_counts)
 
 
+def row_capacity(n: int, num_shards: int, capacity_factor: float) -> int:
+    """Slots per (source, destination) row of the flat exchange of an
+    ``n``-key array over ``num_shards`` shards: ``capacity_factor`` times a
+    row's even share of its shard, rounded up to a multiple of 8."""
+    capacity = int(capacity_factor * -(-(n // num_shards) // num_shards))
+    return capacity + (-capacity) % 8
+
+
 def dist_sort(
     x: jax.Array,
     *,
@@ -124,9 +132,7 @@ def dist_sort(
     n = x.shape[0]
     if n % num_shards:
         raise ValueError(f"n={n} not divisible by shard count {num_shards}")
-    n_local = n // num_shards
-    capacity = int(capacity_factor * -(-n_local // num_shards))
-    capacity += (-capacity) % 8
+    capacity = row_capacity(n, num_shards, capacity_factor)
 
     if method in ("sample", "paper", "valiant"):
         impl = functools.partial(

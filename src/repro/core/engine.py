@@ -919,6 +919,28 @@ class SortEngine:
         self._fn_cache[key] = fn
         return fn
 
+    def _get_dist_fn(self, shape: tuple, dtype, method: str, cf: float):
+        """The jitted ``dist_sort`` over this engine's mesh, one per
+        (shape, dtype, method, capacity factor)."""
+        from repro.core.dist_sort import dist_sort
+
+        key = ("dist", tuple(shape), str(np.dtype(dtype)), method, cf)
+        fn = self._fn_cache.get(key)
+        if fn is None:
+            # update_wrapper names the module jit_dist_sort
+            fn = jax.jit(functools.update_wrapper(
+                functools.partial(
+                    dist_sort,
+                    mesh=self.mesh,
+                    axis_names=self.axis_names,
+                    method=method,
+                    capacity_factor=cf,
+                ),
+                dist_sort,
+            ))
+            self._fn_cache[key] = fn
+        return fn
+
     # ------------------------------------------------------------------ sort
     def sort(self, x, *, plan: SortPlan | None = None) -> np.ndarray:
         """Globally sort ``x``; always exact (overflow escalates capacity).
@@ -1563,7 +1585,7 @@ class SortEngine:
 
     # ------------------------------------------------------------------ dist
     def _sort_dist(self, x_np: np.ndarray, plan: SortPlan, stats) -> np.ndarray:
-        from repro.core.dist_sort import dist_sort
+        from repro.core.dist_sort import row_capacity
 
         if stats is None:
             with jax.profiler.TraceAnnotation(SPAN_PLAN):
@@ -1593,21 +1615,7 @@ class SortEngine:
         retries = 0
         while True:
             with jax.profiler.TraceAnnotation(SPAN_EXECUTE):
-                key = ("dist", x_np.shape, str(x_np.dtype), plan.method, cf)
-                fn = self._fn_cache.get(key)
-                if fn is None:
-                    # update_wrapper names the module jit_dist_sort
-                    fn = jax.jit(functools.update_wrapper(
-                        functools.partial(
-                            dist_sort,
-                            mesh=self.mesh,
-                            axis_names=self.axis_names,
-                            method=plan.method,
-                            capacity_factor=cf,
-                        ),
-                        dist_sort,
-                    ))
-                    self._fn_cache[key] = fn
+                fn = self._get_dist_fn(x_np.shape, x_np.dtype, plan.method, cf)
                 vals, counts = fn(xj)
                 counts = np.asarray(counts).ravel()
             if int(counts.sum()) == x_np.size:
@@ -1620,9 +1628,14 @@ class SortEngine:
             cf = min(float(num_shards), cf * 2.0)
             retries += 1
         with jax.profiler.TraceAnnotation(SPAN_D2H):
-            vals = np.asarray(vals)
+            # One host copy per shard, all started at once: np.asarray of
+            # the global array would copy every slot into a second buffer.
+            by_start = {s.index[0].start or 0: s.data for s in vals.addressable_shards}
+            shards = [by_start[start] for start in sorted(by_start)]
+            for sh in shards:
+                sh.copy_to_host_async()
+            shards = [np.asarray(sh) for sh in shards]
         with jax.profiler.TraceAnnotation(SPAN_UNPACK):
-            shards = np.split(vals, counts.size)
             out = np.concatenate(
                 [sh[: int(c)] for sh, c in zip(shards, counts)]
             )
@@ -1632,6 +1645,15 @@ class SortEngine:
             # elements that sort to the tail and are sliced off below);
             # report caller elements so conservation means counts_sum == n.
             "counts_sum": int(counts.sum()) - pad, "overflow_retries": retries,
+            # The caller's keys each shard received: the pad is the tail of
+            # the shards' concatenation.
+            "shard_counts": np.diff(np.minimum(np.cumsum(counts), n), prepend=0).tolist(),
+            # Slots per (source, destination) row of the attempt that
+            # succeeded; hier sizes its two stages' rows otherwise.
+            "dist_capacity": (
+                None if plan.method == "hier"
+                else row_capacity(x_np.size, num_shards, cf)
+            ),
             "comm_sim_s": (
                 plan.comm_sim_s
                 if plan.comm_sim_s is not None

@@ -5,8 +5,9 @@ described ``v5e:2x2`` topology, and refuses there what the chip would
 refuse (a kernel Mosaic cannot lower, a program past the device's memory).
 Each test compiles one executable family at the smallest shape that still
 exercises it, and checks its temporaries plus arguments fit one chip's
-16 GB of HBM.  The paper-size (2^24) compile is too slow for the suite and
-is made by hand; see ``chip_smoke.py``.
+16 GB of HBM.  The paper-size (2^24) sim compile is too slow for the suite
+and is made by hand; see ``chip_smoke.py``.  The dist sort compiles at the
+paper's 60 MB over all four chips, the size its benchmark cell runs.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and pytest-xdist workers import
@@ -29,27 +30,36 @@ HBM_BYTES = 16e9  # one v5e chip
 @pytest.fixture(scope="module")
 def topo():
     from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
-        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+        desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-
-
-@pytest.fixture(scope="module")
-def one_chip(topo):
-    from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
-
     # A described-topology compile is written to the persistent cache but
     # cannot be read back without a chip; keep the cache out of the way.
     before = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield desc
     jax.config.update("jax_enable_compilation_cache", before)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    """A ``("data",)`` mesh over the four described chips."""
+    from jax.sharding import Mesh
+
+    return Mesh(np.array(topo.devices), ("data",))
 
 
 def _spec(shape, dtype, sharding):
@@ -110,3 +120,24 @@ def test_top_k_compiles_for_v5e(one_chip):
         _spec((n,), jnp.int32, one_chip), _spec((), jnp.int32, one_chip)
     ).compile()
     _fits_one_chip(compiled)
+
+
+def test_dist_sort_compiles_for_v5e(four_chips):
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from repro.core.dist_sort import row_capacity
+
+    n, shards = 15_728_640, 4  # the paper's 60 MB of int32
+    eng = SortEngine(mesh=four_chips)
+    plan = eng.plan(make_array("random", n, seed=0))
+    assert (plan.path, plan.method) == ("dist", "paper")
+    cf = 2.0  # _sort_dist's floor, which uniform keys take
+    fn = eng._get_dist_fn((n,), np.int32, plan.method, cf)
+    compiled = fn.lower(
+        _spec((n,), jnp.int32, NamedSharding(four_chips, PartitionSpec("data")))
+    ).compile()
+    _fits_one_chip(compiled)  # per-chip figures for an SPMD program
+    text = compiled.as_text()
+    assert "all-to-all" in text
+    # each chip's (4, capacity) rows go out in one exchange
+    assert f"s32[{shards},1,{row_capacity(n, shards, cf)}]" in text
