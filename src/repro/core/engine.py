@@ -51,7 +51,8 @@ import dataclasses
 import functools
 import math
 import os
-from typing import Callable, Sequence
+import threading
+from typing import TYPE_CHECKING, Callable, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -64,6 +65,9 @@ from repro.core.topology import OHHCTopology
 from repro.core.workloads import TopKTooLarge
 from repro.kernels import batched as batched_kernels
 from repro.kernels import ops
+
+if TYPE_CHECKING:
+    from concurrent.futures import Executor, ThreadPoolExecutor
 
 # Granularity cap for stats histograms: coarser than P only ever
 # *over*-estimates the max bucket fraction (refining buckets can't raise it).
@@ -124,6 +128,35 @@ measures what the host spends in its stage, and the trace's device lines
 show the rest.  Every executable the engine builds has a stable module
 name (``jit_sim_sort``, ``jit_row_sort``, ``jit_batch_row_sort``,
 ``jit_pairs_sort``, ``jit_sim_topk``, ``jit_dist_sort``)."""
+
+
+def join_prefixes(
+    parts: Sequence[np.ndarray], counts: Sequence[int], pool: Executor | None
+) -> tuple[np.ndarray, int]:
+    """``np.concatenate([p[:c] for p, c in zip(parts, counts)])`` into one
+    fresh array, and the number of threads that wrote it.
+
+    Writing fresh memory is bound by first-touch page faults, not by
+    bandwidth, so with a ``pool`` every part gets a writer of its own,
+    copying into its own slice of the output at once with the others
+    (numpy releases the GIL for a same-dtype copy).  With one part, or no
+    pool, the calling thread copies.  ``parts`` share a dtype.
+    """
+    counts = [int(c) for c in counts]
+    offsets = np.cumsum([0, *counts])
+    out = np.empty(int(offsets[-1]), parts[0].dtype)
+
+    def write(i: int) -> None:
+        out[offsets[i] : offsets[i + 1]] = parts[i][: counts[i]]
+
+    if pool is None or len(parts) == 1:
+        for i in range(len(parts)):
+            write(i)
+        return out, 1
+    futures = [pool.submit(write, i) for i in range(len(parts))]
+    for f in futures:
+        f.result()
+    return out, len(parts)
 
 
 def choose_row_backend() -> tuple[str, str]:
@@ -704,6 +737,9 @@ class SortEngine:
         self._fault_info: dict[str, dict] = {}
         self.trace_count = 0  # incremented once per actual jit trace
         self.last_report: dict | None = None
+        # The dist path's join writers, one per shard, made on first use.
+        self._join_pool: ThreadPoolExecutor | None = None
+        self._join_pool_lock = threading.Lock()
 
     # ---------------------------------------------------------------- faults
     def set_fault_scenario(self, scenario) -> None:
@@ -1636,9 +1672,7 @@ class SortEngine:
                 sh.copy_to_host_async()
             shards = [np.asarray(sh) for sh in shards]
         with jax.profiler.TraceAnnotation(SPAN_UNPACK):
-            out = np.concatenate(
-                [sh[: int(c)] for sh, c in zip(shards, counts)]
-            )
+            out, writers = join_prefixes(shards, counts, self._join_writers(len(shards)))
         self.last_report = {
             "plan": plan, "n": n, "stats": stats,
             # counts includes the shard-divisibility pad (max-sentinel
@@ -1659,5 +1693,22 @@ class SortEngine:
                 if plan.comm_sim_s is not None
                 else self.comm_cost_estimate(n, itemsize=x_np.dtype.itemsize)
             ),
+            "unpack_writers": writers,
         }
         return out[:n]
+
+    def _join_writers(self, num_shards: int) -> ThreadPoolExecutor | None:
+        """The pool that joins the dist path's shards on the host, one
+        worker per shard, shared by concurrent callers; ``None`` for one
+        shard."""
+        if num_shards == 1:
+            return None
+        with self._join_pool_lock:
+            if self._join_pool is None:
+                # Imported here: a process with no mesh engine never loads it.
+                from concurrent.futures import ThreadPoolExecutor
+
+                self._join_pool = ThreadPoolExecutor(
+                    num_shards, thread_name_prefix="sort_join"
+                )
+            return self._join_pool
