@@ -31,9 +31,15 @@ from benchmarks.common import (
     resolve_dtype,
     sizes_mb,
 )
-from repro.core import OHHCTopology, SortEngine, SortPlan, default_capacity, x64_enabled
+from repro.core import (
+    OHHCTopology,
+    SortEngine,
+    SortPlan,
+    bucketed_length,
+    default_capacity,
+    x64_enabled,
+)
 from repro.data.distributions import ALL_DISTRIBUTIONS, make_array
-from repro.kernels import ops
 
 FIXED_METHODS = ("paper", "sampled")
 ROUNDS = 3
@@ -45,7 +51,7 @@ def _fixed_plan(eng: SortEngine, n: int, method: str, dtype) -> SortPlan:
         # 64-bit keys have no exact jit path without x64 — the fixed
         # baseline must take the same host detour the engine does.
         return SortPlan("host", method, None, None, "fixed baseline")
-    padded = ops.bucketed_length(n)
+    padded = bucketed_length(n)
     cap = default_capacity(padded, eng.topo.total_procs)
     return SortPlan("sim", method, cap, padded, "fixed baseline")
 

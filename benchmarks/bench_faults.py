@@ -12,12 +12,11 @@ For each k-link fault scenario the engine can serve through
   actually does.
 
 The derived column carries both slowdown ratios plus their agreement
-(``measured / predicted``).  The in-bench gate (the ``bench_kernels``
-autotune-slack precedent) pins the model contract: a degraded gather must
-actually be slower (measured ≥ 1), the BSP prediction must be
-conservative (measured ≤ predicted, within slack — dependency mode
-overlaps rounds the barrier model serializes), and the agreement must not
-collapse (a prediction several times the measured cost would make the
+(``measured / predicted``).  The in-bench gate pins the model contract:
+a degraded gather must actually be slower (measured ≥ 1), the BSP
+prediction must be conservative (measured ≤ predicted, within slack —
+dependency mode overlaps rounds the barrier model serializes), and the
+agreement must not collapse (a prediction several times the measured cost would make the
 engine's quoted slowdowns meaningless).  Impossible scenarios (an
 optically islanded group, a dead hub node) are emitted as rows too — the
 typed ``GatherImpossible`` verdict with the offending node count is the

@@ -111,60 +111,6 @@ def _bench_segmented_vs_loop(paper: bool, dtype, report: dict) -> None:
     report["throughput"] = rows
 
 
-def _bench_row_backend_ab(paper: bool, dtype, report: dict) -> None:
-    """Forced-plan A/B of the segment row backends through the whole
-    ``sort_segments`` serving path (pack → kernel → unpack), not just the
-    kernel: ``vmap`` (vmapped XLA sort) vs the fused Pallas batched kernel
-    and its 2-op variant (DESIGN.md §8).  The plan is forced per round so
-    the measurement is immune to the autotune's own choice.
-    """
-    from repro.core import SortPlan
-    from repro.kernels import ops as kops
-
-    eng = SortEngine(OHHCTopology(1, "full"))
-    rng = bench_rng(13)
-    B = 16 if common.SMOKE else 64
-    arrs = _make_batch(rng, B, dtype, lo=256, hi=1024)
-    lens = [a.size for a in arrs]
-    flat = np.concatenate(arrs)
-    padded_n = kops.bucketed_length(max(lens))
-    methods = {"vmap": "bitonic", "pallas": "bitonic_pallas"}
-    if np.issubdtype(np.dtype(dtype), np.integer):
-        methods["pallas2op"] = "bitonic2op"
-    plans = {
-        name: SortPlan("sim", m, None, padded_n, "bench row-backend A/B")
-        for name, m in methods.items()
-    }
-    expect = [np.sort(a) for a in arrs]
-    for plan in plans.values():  # warm (compile) + correctness check once
-        for g, e in zip(eng.sort_segments(flat, lens, plan=plan), expect):
-            np.testing.assert_array_equal(g, e)
-    meas = measure_interleaved(
-        {
-            name: (lambda p=plan: eng.sort_segments(flat, lens, plan=p))
-            for name, plan in plans.items()
-        },
-        warmup=0,
-        repeats=ROUNDS,
-    )
-    t_vmap = meas["vmap"].median_s
-    rows = {}
-    for name, m in meas.items():
-        ratio = t_vmap / m.median_s if m.median_s > 0 else float("inf")
-        rows[name] = {
-            "method": methods[name],
-            "median_s": m.median_s,
-            "iqr_s": m.iqr_s,
-            "vs_vmap": ratio,
-        }
-        emit(
-            f"sortd/rowbackend/{name}/B{B}xL{padded_n}",
-            m.median_s * 1e6,
-            f"vs_vmap={ratio:.2f};iqr_us={m.iqr_s * 1e6:.0f}",
-        )
-    report["row_backend_ab"] = {"batch": B, "padded_n": padded_n, "rows": rows}
-
-
 def _emit_service_metrics(mode: str, m: dict, wall_s: float, n_req: int) -> None:
     emit(
         f"sortd/{mode}/total",
@@ -272,7 +218,6 @@ def run(
         "config": {"arrival": arrival, "rate": rate, "clients": clients},
     }
     _bench_segmented_vs_loop(paper, dt, doc)
-    _bench_row_backend_ab(paper, dt, doc)
     if arrival != "none":
         _bench_service(paper, dt, arrival, rate, clients, doc)
     if report:

@@ -28,7 +28,6 @@ from benchmarks import (
     bench_engine,
     bench_faults,
     bench_fleet,
-    bench_kernels,
     bench_moe_dispatch,
     bench_netsim,
     bench_parallel,
@@ -51,7 +50,6 @@ SUITES = {
     "efficiency_half": lambda a: bench_efficiency.run(a.paper, "half"),  # 6.16–19
     "counters": lambda a: bench_counters.run(a.paper),  # 6.20–6.24
     "commsteps": lambda a: bench_commsteps.run(a.paper),  # Theorem 3
-    "kernels": lambda a: bench_kernels.run(a.paper),
     "moe_dispatch": lambda a: bench_moe_dispatch.run(a.paper),
     "engine": lambda a: bench_engine.run(
         a.paper, dtype=a.dtype or DEFAULT_DTYPE

@@ -1,7 +1,7 @@
 """Quickstart: the paper's parallel Quick Sort on the OHHC, end to end.
 
-Runs the faithful algorithm (value-range buckets → per-processor bitonic
-local sort → 3-phase hierarchical accumulation) on a 1-D full OHHC
+Runs the faithful algorithm (value-range buckets → per-processor local
+sort → 3-phase hierarchical accumulation) on a 1-D full OHHC
 (36 processors), validates the result, and prints the schedule facts the
 paper proves analytically (Theorems 3/6).
 
@@ -23,7 +23,6 @@ from repro.core import (
     ohhc_sort_sim,
 )
 from repro.data.distributions import ALL_DISTRIBUTIONS, make_array
-from repro.kernels import ops
 
 
 def main():
@@ -33,10 +32,8 @@ def main():
 
     x = make_array("random", 1 << 16, seed=0)
 
-    # simulated-processor path with the Pallas bitonic local sort
-    out, counts = ohhc_sort_sim(
-        jnp.asarray(x), topo, local_sort=ops.make_local_sort()
-    )
+    # simulated-processor path; each processor's local sort is XLA's sort
+    out, counts = ohhc_sort_sim(jnp.asarray(x), topo)
     assert np.array_equal(np.asarray(out), np.sort(x))
     print(f"sorted {x.size} ints; bucket imbalance max/mean = "
           f"{float(counts.max())/float(counts.mean()):.2f}")

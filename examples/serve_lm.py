@@ -1,7 +1,7 @@
 """Serve a small model with batched requests.
 
-Batch formation sorts requests by prompt length with the bitonic pair-sort
-kernel (the paper's primitive in its serving role), then prefill + greedy
+Batch formation sorts requests by prompt length with the engine's pairs
+sort (the paper's primitive in its serving role), then prefill + greedy
 decode with a padded KV cache.
 
     PYTHONPATH=src python examples/serve_lm.py

@@ -1,7 +1,7 @@
 """repro: OTIS Hyper Hexa-Cell parallel Quick Sort as a multi-pod JAX framework.
 
-Layers: core (the paper's algorithm + distributed sorts), kernels (Pallas
-TPU: bitonic sort, bucket partition), models (10 assigned architectures),
+Layers: core (the paper's algorithm, the sort engine and distributed
+sorts; local sorts are XLA's), models (10 assigned architectures),
 configs, data, optim, train, serve, ckpt, runtime (fault tolerance, PP,
 collectives), launch (mesh/dryrun/train/serve), roofline.
 

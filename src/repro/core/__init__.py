@@ -12,6 +12,7 @@ streaming-merge operations, DESIGN.md §12).
 from repro.core.topology import OHHCTopology, table_1_1, HHC_SIZE
 from repro.core.schedule import AccumulationSchedule, payload_bytes_per_round
 from repro.core.partition import (
+    bucketed_length,
     default_capacity,
     pack_segments,
     paper_bucket_ids,
@@ -42,8 +43,6 @@ from repro.core.workloads import (
     topk_cut,
 )
 from repro.core.engine import (
-    BITONIC_METHODS,
-    ROW_BACKENDS,
     SEGMENT_BITONIC_MAX,
     InputStats,
     SortEngine,
@@ -51,15 +50,12 @@ from repro.core.engine import (
     autotune_capacity,
     choose_batch_plan,
     choose_plan,
-    choose_row_backend,
     estimate_batch_stats,
     estimate_stats,
     x64_enabled,
 )
 
 __all__ = [
-    "BITONIC_METHODS",
-    "ROW_BACKENDS",
     "SEGMENT_BITONIC_MAX",
     "InputStats",
     "SortEngine",
@@ -67,7 +63,6 @@ __all__ = [
     "autotune_capacity",
     "choose_batch_plan",
     "choose_plan",
-    "choose_row_backend",
     "estimate_batch_stats",
     "estimate_stats",
     "x64_enabled",
@@ -76,6 +71,7 @@ __all__ = [
     "HHC_SIZE",
     "AccumulationSchedule",
     "payload_bytes_per_round",
+    "bucketed_length",
     "default_capacity",
     "pack_segments",
     "unpack_segments",

@@ -46,6 +46,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro import compat
 from repro.core import partition
 
+__all__ = ["dist_sort", "host_check_globally_sorted", "row_capacity"]
 
 # Typed scalar (a bare Python int would be weak-typed int32 and overflow
 # for uint32 where it feeds jnp.where directly).

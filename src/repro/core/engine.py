@@ -50,7 +50,6 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-import os
 import threading
 from typing import TYPE_CHECKING, Callable, Sequence
 
@@ -63,8 +62,6 @@ from repro.core import partition, workloads
 from repro.core.ohhc_sort import ohhc_sort_host
 from repro.core.topology import OHHCTopology
 from repro.core.workloads import TopKTooLarge
-from repro.kernels import batched as batched_kernels
-from repro.kernels import ops
 
 if TYPE_CHECKING:
     from concurrent.futures import Executor, ThreadPoolExecutor
@@ -74,26 +71,9 @@ if TYPE_CHECKING:
 _MAX_STAT_BUCKETS = 256
 
 # Largest row bucket the segmented batch path sorts with the direct
-# sentinel-padded bitonic row kernel instead of the P-way bucket machinery
-# (see choose_batch_plan).
+# sentinel-padded row sort (plan method ``"bitonic"``: no capacity, no
+# overflow) instead of the P-way bucket machinery (see choose_batch_plan).
 SEGMENT_BITONIC_MAX = 1 << 13
-
-# The row-sort backends the bitonic segment path can run on (DESIGN.md §8):
-# ``vmap`` is the vmapped XLA-level sort, ``pallas`` the fused batched
-# Pallas kernel (``kernels/batched.py``, sentinel-fill + sort + validity
-# mask in ONE pallas_call with the grid over the batch axis), ``pallas2op``
-# the same kernel with the NICE 2-op compare-exchange stage.  Each backend
-# is a distinct plan method so the jit cache, ``SortPlan.reason`` and the
-# sortd metrics all name the executed kernel.
-ROW_BACKENDS = ("vmap", "pallas", "pallas2op")
-_BACKEND_METHODS = {
-    "vmap": "bitonic",
-    "pallas": "bitonic_pallas",
-    "pallas2op": "bitonic2op",
-}
-# Every method string that means "direct sentinel-padded row sort" — no
-# capacity, no overflow (the complement of the bucket-path methods).
-BITONIC_METHODS = tuple(_BACKEND_METHODS.values())
 
 # Largest input the sim path takes by default; ``host_threshold`` is one
 # past it.  n ≤ 2^24 covers the paper's 10–60 MB int32 arrays and pads to
@@ -126,8 +106,8 @@ for it, so an overflow retry shows as a second span), ``SPAN_D2H``,
 only while a profiler runs, on its clock.  No span adds a sync: each
 measures what the host spends in its stage, and the trace's device lines
 show the rest.  Every executable the engine builds has a stable module
-name (``jit_sim_sort``, ``jit_row_sort``, ``jit_batch_row_sort``,
-``jit_pairs_sort``, ``jit_sim_topk``, ``jit_dist_sort``)."""
+name (``jit_sim_sort``, ``jit_row_sort``, ``jit_pairs_sort``,
+``jit_sim_topk``, ``jit_dist_sort``)."""
 
 
 def join_prefixes(
@@ -157,24 +137,6 @@ def join_prefixes(
     for f in futures:
         f.result()
     return out, len(parts)
-
-
-def choose_row_backend() -> tuple[str, str]:
-    """Row-sort backend for bitonic segment rows: ``vmap`` (the vmapped XLA
-    ``jnp.sort``) on every backend, with no probe.
-
-    The fused Pallas row kernels (``pallas`` / ``pallas2op``) do not compile
-    for TPU v5e, so they run only when a caller asks for them:
-    ``REPRO_ROW_BACKEND`` forces a backend, or an explicit plan names its
-    method.  A forced kernel the chip's compiler refuses raises there.
-    Returns ``(backend, detail)``; ``detail`` lands in ``SortPlan.reason``.
-    """
-    forced = os.environ.get("REPRO_ROW_BACKEND", "").strip().lower()
-    if not forced:
-        return "vmap", "row_backend=vmap (default)"
-    if forced not in ROW_BACKENDS:
-        raise ValueError(f"REPRO_ROW_BACKEND={forced!r} not in {ROW_BACKENDS}")
-    return forced, f"row_backend={forced} (forced via REPRO_ROW_BACKEND)"
 
 
 def x64_enabled() -> bool:
@@ -439,6 +401,19 @@ def autotune_capacity(
     return cap
 
 
+def _grow_capacity(capacity: int, padded_n: int) -> int:
+    """The capacity to retry with after a bucket overflowed ``capacity``:
+    ×2, rounded up to 8, at most ``padded_n``.
+
+    A capacity of ``padded_n`` holds every key in one bucket, so it cannot
+    overflow; an overflow there is a fault, not a model miss, and raises.
+    """
+    if capacity >= padded_n:
+        raise AssertionError("overflow with capacity == padded_n")
+    capacity = min(padded_n, capacity * 2)
+    return capacity + (-capacity) % 8
+
+
 def choose_batch_plan(
     stats: InputStats | None,
     num_buckets: int,
@@ -446,7 +421,6 @@ def choose_batch_plan(
     *,
     margin: float = 1.25,
     bitonic_max: int = SEGMENT_BITONIC_MAX,
-    row_backend: str | None = None,
 ) -> SortPlan:
     """Plan ONE fused ``(B, padded_n)`` sim call for a segment batch.
 
@@ -455,16 +429,12 @@ def choose_batch_plan(
     point of coalescing — so the decisions left are the per-row kernel and
     one shared capacity:
 
-    * rows up to ``bitonic_max`` take a bitonic method — a direct
-      sentinel-padded row sort with **no** value partitioning.  At serving
-      row sizes the P-way bucket machinery (a stable sort by bucket id,
-      row slices, P per-bucket sorts) costs more device time than
-      sorting the row outright, needs no capacity, and is immune to value
-      skew — the fused batch IS the parallelism.  ``row_backend`` selects
-      the kernel (:data:`ROW_BACKENDS`): ``vmap`` → ``bitonic`` (vmapped
-      XLA sort, the default), ``pallas`` → ``bitonic_pallas`` (the fused
-      batched Pallas kernel), ``pallas2op`` → ``bitonic2op`` (its NICE
-      2-op stage); the engine feeds this from :func:`choose_row_backend`;
+    * rows up to ``bitonic_max`` take the ``bitonic`` method — a direct
+      sentinel-padded row sort (the vmapped XLA sort) with **no** value
+      partitioning.  At serving row sizes the P-way bucket machinery (a
+      stable sort by bucket id, row slices, P per-bucket sorts) costs more
+      device time than sorting the row outright, needs no capacity, and is
+      immune to value skew — the fused batch IS the parallelism;
     * longer rows run the paper's bucket path: ``sampled`` splitters when
       the worst row is skewed but not duplicate-dominated (quantile
       splitters cannot split one repeated value), else the equal-width
@@ -473,13 +443,9 @@ def choose_batch_plan(
       overflowing it.
     """
     if padded_n <= bitonic_max:
-        backend = row_backend or "vmap"
-        if backend not in _BACKEND_METHODS:
-            raise ValueError(f"row_backend {backend!r} not in {ROW_BACKENDS}")
         return SortPlan(
-            "sim", _BACKEND_METHODS[backend], None, padded_n,
-            f"segmented bitonic rows (Lbucket={padded_n} ≤ {bitonic_max}), "
-            f"row_backend={backend}",
+            "sim", "bitonic", None, padded_n,
+            f"segmented bitonic rows (Lbucket={padded_n} ≤ {bitonic_max})",
         )
     if stats is None:
         raise ValueError("choose_batch_plan needs stats for the bucket path")
@@ -550,7 +516,7 @@ def choose_plan(
             "host", "paper", None, None,
             "large skewed input: dense (P, capacity) buffer would dwarf n",
         )
-    padded_n = ops.bucketed_length(stats.n)
+    padded_n = partition.bucketed_length(stats.n)
     cap = autotune_capacity(stats, method, P, padded_n, margin=margin)
     return SortPlan(
         "sim", method, cap, padded_n,
@@ -604,7 +570,6 @@ def _sim_topk_padded(
     P: int,
     keep: int,
     capacity: int,
-    local_sort: Callable[[jax.Array], jax.Array],
 ):
     """Partial range-partition sort: the top-k skip rule on the sim path.
 
@@ -634,7 +599,7 @@ def _sim_topk_padded(
         jnp.where(kept, x_pad, fill), ids, keep + 1, capacity, fill_value=fill
     )
     buckets, counts = buckets[:keep], counts[:keep]
-    buckets = jax.vmap(local_sort)(buckets)
+    buckets = jax.vmap(jnp.sort)(buckets)
     head = partition.unscatter(buckets, counts, min(n_pad, keep * capacity))
     return head, counts, kept_total
 
@@ -647,7 +612,6 @@ def _sim_sort_padded(
     capacity: int,
     method: str,
     sample_size: int,
-    local_sort: Callable[[jax.Array], jax.Array],
 ):
     """Sort the valid prefix of a padded buffer on P simulated processors.
 
@@ -681,7 +645,7 @@ def _sim_sort_padded(
         jnp.where(valid, x_pad, fill), ids, P + 1, capacity, fill_value=fill
     )
     buckets, counts = buckets[:P], counts[:P]
-    buckets = jax.vmap(local_sort)(buckets)
+    buckets = jax.vmap(jnp.sort)(buckets)
     out = partition.unscatter(buckets, counts, n_pad)
     return out, counts
 
@@ -700,8 +664,6 @@ class SortEngine:
                      dispatch to ``dist_sort`` over the mesh.
     host_threshold:  sizes ≥ this go to the exact numpy path (default
                      :data:`HOST_THRESHOLD`, one past :data:`SIM_MAX_N`).
-    local_sort:      per-bucket sorter for the sim path (default
-                     ``jnp.sort``, XLA's sort on every backend).
     fault_scenario:  optional ``net.faults.FaultScenario`` the engine serves
                      under (DESIGN.md §11): plans re-price the gather over
                      the degraded topology (``SortPlan.fault_slowdown``) and
@@ -719,7 +681,6 @@ class SortEngine:
         host_threshold: int = HOST_THRESHOLD,
         sample_size: int = 2048,
         margin: float = 1.25,
-        local_sort: Callable[[jax.Array], jax.Array] | None = None,
         fault_scenario=None,
     ):
         self.topo = topo if topo is not None else OHHCTopology(1, "full")
@@ -728,7 +689,6 @@ class SortEngine:
         self.host_threshold = int(host_threshold)
         self.sample_size = int(sample_size)
         self.margin = float(margin)
-        self.local_sort = local_sort if local_sort is not None else jnp.sort
         self.fault_scenario = fault_scenario
         self._fn_cache: dict[tuple, Callable] = {}
         self._comm_sim_cache: dict[tuple, float] = {}
@@ -854,7 +814,7 @@ class SortEngine:
         from repro.net.links import LinkModel
         from repro.net.sim import simulate_gather, simulate_schedule
 
-        bucket = ops.bucketed_length(max(2, n))
+        bucket = partition.bucketed_length(max(2, n))
         name = None if fault_info is None else fault_info["scenario"].name
         key = ("netsim", bucket, itemsize, name)
         t = self._comm_sim_cache.get(key)
@@ -904,28 +864,6 @@ class SortEngine:
         fn = self._fn_cache.get(key)
         if fn is not None:
             return fn
-        if method in ("bitonic_pallas", "bitonic2op"):
-            # The fused batched Pallas kernel (kernels/batched.py): ONE
-            # pallas_call whose grid IS the batch axis, sentinel-fill +
-            # sort + validity mask per row — no vmap wrapper, the whole
-            # (B, L) batch goes in.  Counts are the trivial per-row totals
-            # (same no-overflow contract as the vmapped bitonic method).
-            if not batched:
-                raise ValueError(f"method {method!r} is batch-only")
-            interpret = ops._auto_interpret(None)
-            kernel_method = "bitonic2op" if method == "bitonic2op" else "bitonic"
-
-            def batch_row_sort(x_pad, n_valid):
-                self.trace_count += 1  # runs at trace time only
-                out = batched_kernels.batched_row_sort(
-                    x_pad, n_valid, method=kernel_method, interpret=interpret
-                )
-                return out, n_valid.astype(jnp.int32)[:, None]
-
-            fn = jax.jit(batch_row_sort)
-            self._fn_cache[key] = fn
-            return fn
-
         def row_sort(x_pad, n_valid):
             # Direct sentinel-padded row sort (segmented batch rows,
             # DESIGN.md §8): pad cells carry the dtype max, which sorts to
@@ -934,7 +872,7 @@ class SortEngine:
             # this kernel cannot overflow.
             self.trace_count += 1  # runs at trace time only
             return (
-                self.local_sort(x_pad),
+                jnp.sort(x_pad),
                 jnp.reshape(n_valid.astype(jnp.int32), (1,)),
             )
 
@@ -947,7 +885,6 @@ class SortEngine:
                 capacity=capacity,
                 method=method,
                 sample_size=min(self.sample_size, padded_n),
-                local_sort=self.local_sort,
             )
 
         traced = row_sort if method == "bitonic" else sim_sort
@@ -1024,7 +961,7 @@ class SortEngine:
 
     def _sort_sim(self, x_np: np.ndarray, plan: SortPlan, stats) -> np.ndarray:
         n = x_np.size
-        padded_n = plan.padded_n or ops.bucketed_length(n)
+        padded_n = plan.padded_n or partition.bucketed_length(n)
         capacity = plan.capacity or partition.default_capacity(padded_n, self.topo.total_procs)
         with jax.profiler.TraceAnnotation(SPAN_PAD):
             x_pad = np.zeros(padded_n, x_np.dtype)
@@ -1039,12 +976,8 @@ class SortEngine:
                 got = int(jnp.sum(counts))
             if got == n:
                 break
-            # Measured-model miss: escalate capacity (×2, cap at padded_n —
-            # which by construction cannot overflow) and re-run.
-            if capacity >= padded_n:
-                raise AssertionError("overflow with capacity == padded_n")
-            capacity = min(padded_n, capacity * 2)
-            capacity += (-capacity) % 8
+            # Measured-model miss: escalate capacity and re-run.
+            capacity = _grow_capacity(capacity, padded_n)
             retries += 1
         with jax.profiler.TraceAnnotation(SPAN_D2H):
             out = np.asarray(out)[:n]
@@ -1065,7 +998,7 @@ class SortEngine:
         """
         keys = np.asarray(keys).ravel()
         lens = np.asarray(seg_lens, dtype=np.int64).ravel()
-        padded_n = ops.bucketed_length(int(lens.max()) if lens.size else 1)
+        padded_n = partition.bucketed_length(int(lens.max()) if lens.size else 1)
         stats = None
         if padded_n > SEGMENT_BITONIC_MAX:
             padded = partition.pack_segments(keys, lens, padded_n)
@@ -1073,15 +1006,9 @@ class SortEngine:
                 padded, lens,
                 num_buckets=min(self.topo.total_procs, _MAX_STAT_BUCKETS),
             )
-            return choose_batch_plan(
-                stats, self.topo.total_procs, padded_n, margin=self.margin
-            )
-        backend, detail = choose_row_backend()
-        plan = choose_batch_plan(
-            None, self.topo.total_procs, padded_n,
-            margin=self.margin, row_backend=backend,
+        return choose_batch_plan(
+            stats, self.topo.total_procs, padded_n, margin=self.margin
         )
-        return dataclasses.replace(plan, reason=f"{plan.reason}; {detail}")
 
     def sort_segments(
         self, keys, seg_lens, *, plan: SortPlan | None = None,
@@ -1125,49 +1052,31 @@ class SortEngine:
         total = keys.size
         max_n = int(lens.max()) if B else 0
         if keys.dtype.itemsize == 8 and not x64_enabled():
-            if return_padded:
-                raise ValueError(
-                    "return_padded needs the jit path; 64-bit keys without "
-                    "x64 only have the exact host fallback"
-                )
-            outs = [
-                np.sort(seg)
-                for seg in np.split(keys, np.cumsum(lens)[:-1])
-            ] if B else []
-            self.last_report = {
-                "plan": SortPlan(
+            return self._sort_segments_on_host(
+                keys, lens, return_padded,
+                "64-bit keys without x64 only have the exact host fallback",
+                SortPlan(
                     "host", "paper", None, None,
                     f"{keys.dtype} segments without jax x64: exact host fallback",
                 ),
-                "n": total, "batch": B, "overflow_retries": 0,
-            }
-            return outs
+            )
         fault_info = self._fault_state()
         if fault_info is not None and fault_info["impossible"]:
-            # The batched twin of the 64-bit host fallback above: an
-            # impossible scenario has no degraded gather to run, so serve
+            # An impossible scenario has no degraded gather to run, so serve
             # the batch exactly on the healthy host path (DESIGN.md §11).
-            if return_padded:
-                raise ValueError(
-                    "return_padded needs the jit path; fault scenario "
-                    f"{fault_info['scenario'].name!r} makes the degraded "
-                    "gather impossible and forces the host fallback"
-                )
-            outs = [
-                np.sort(seg)
-                for seg in np.split(keys, np.cumsum(lens)[:-1])
-            ] if B else []
-            self.last_report = {
-                "plan": SortPlan(
+            name = fault_info["scenario"].name
+            return self._sort_segments_on_host(
+                keys, lens, return_padded,
+                f"fault scenario {name!r} makes the degraded gather "
+                "impossible and forces the host fallback",
+                SortPlan(
                     "host", "paper", None, None,
-                    f"fault={fault_info['scenario'].name}: degraded gather "
-                    f"impossible ({fault_info['detail']}); exact host fallback",
-                    fault=fault_info["scenario"].name,
+                    f"fault={name}: degraded gather impossible "
+                    f"({fault_info['detail']}); exact host fallback",
+                    fault=name,
                 ),
-                "n": total, "batch": B, "overflow_retries": 0,
-            }
-            return outs
-        padded_n = ops.bucketed_length(max(max_n, 1))
+            )
+        padded_n = partition.bucketed_length(max(max_n, 1))
         if B == 0 or max_n <= 1:
             # Nothing to sort row-wise; keep the trivial case off the device.
             self.last_report = {
@@ -1196,23 +1105,16 @@ class SortEngine:
         padded = partition.pack_segments(keys, lens_pad, padded_n)
         stats = None
         if plan is None:
-            if padded_n <= SEGMENT_BITONIC_MAX:
-                # the bitonic row kernels need no capacity → no stats pass;
-                # the backend is vmap unless REPRO_ROW_BACKEND forces one
-                backend, detail = choose_row_backend()
-                plan = choose_batch_plan(
-                    None, self.topo.total_procs, padded_n,
-                    margin=self.margin, row_backend=backend,
-                )
-                plan = dataclasses.replace(plan, reason=f"{plan.reason}; {detail}")
-            else:
+            if padded_n > SEGMENT_BITONIC_MAX:
+                # bitonic rows need no capacity, so only bucket rows pay
+                # for the stats pass
                 stats = estimate_batch_stats(
                     padded, lens_pad,
                     num_buckets=min(self.topo.total_procs, _MAX_STAT_BUCKETS),
                 )
-                plan = choose_batch_plan(
-                    stats, self.topo.total_procs, padded_n, margin=self.margin
-                )
+            plan = choose_batch_plan(
+                stats, self.topo.total_procs, padded_n, margin=self.margin
+            )
         # Degraded-but-possible scenario: same fused sim path, plan
         # annotated with the predicted gather slowdown (impossible was
         # already rerouted to the host fallback above).
@@ -1220,7 +1122,7 @@ class SortEngine:
         if plan.path != "sim":
             raise ValueError(f"sort_segments only runs the sim path, got {plan.path!r}")
         method = plan.method
-        capacity = 0 if method in BITONIC_METHODS else (
+        capacity = 0 if method == "bitonic" else (
             plan.capacity
             or partition.default_capacity(padded_n, self.topo.total_procs)
         )
@@ -1233,14 +1135,11 @@ class SortEngine:
             per_row = np.asarray(jnp.sum(counts, axis=-1))
             if np.array_equal(per_row, lens_pad):
                 break
-            if capacity >= padded_n:
-                raise AssertionError("overflow with capacity == padded_n")
-            capacity = min(padded_n, capacity * 2)
-            capacity += (-capacity) % 8
+            capacity = _grow_capacity(capacity, padded_n)
             retries += 1
         self.last_report = {
             "plan": dataclasses.replace(
-                plan, capacity=capacity if method not in BITONIC_METHODS else None
+                plan, capacity=capacity if method != "bitonic" else None
             ),
             "n": total, "stats": stats, "batch": B, "batch_padded": B_pad,
             "overflow_retries": retries,
@@ -1249,6 +1148,22 @@ class SortEngine:
         if return_padded:
             return out[:B]
         return partition.unpack_segments(np.asarray(out)[:B], lens)
+
+    def _sort_segments_on_host(
+        self, keys: np.ndarray, lens: np.ndarray, return_padded: bool,
+        why: str, plan: SortPlan,
+    ) -> list[np.ndarray]:
+        """``sort_segments``' exact host fallback: one ``np.sort`` per
+        segment.  It has no device output, so ``return_padded`` raises
+        with ``why``; ``plan`` is what ``last_report`` records."""
+        if return_padded:
+            raise ValueError(f"return_padded needs the jit path; {why}")
+        segs = np.split(keys, np.cumsum(lens)[:-1]) if lens.size else []
+        self.last_report = {
+            "plan": plan, "n": keys.size, "batch": int(lens.size),
+            "overflow_retries": 0,
+        }
+        return [np.sort(seg) for seg in segs]
 
     def sort_many(self, xs: Sequence) -> list[np.ndarray]:
         """Sort a batch of arrays with ONE vmapped executable.
@@ -1323,7 +1238,7 @@ class SortEngine:
         n = keys.shape[0]
         if n <= 1:
             return keys, vals
-        n_pad = ops.bucketed_length(n)
+        n_pad = partition.bucketed_length(n)
         fn = self._get_pairs_fn(n_pad, keys.dtype, vals.dtype)
         fill = _sim_fill(keys.dtype)
         kp = jnp.concatenate([keys, jnp.full((n_pad - n,), fill, keys.dtype)])
@@ -1365,7 +1280,7 @@ class SortEngine:
         ks, perm = self._sort_pairs_flat(keys_np, np.arange(n, dtype=np.int32))
         self.last_report = {
             "plan": SortPlan(
-                "sim", "pairs", None, ops.bucketed_length(n),
+                "sim", "pairs", None, partition.bucketed_length(n),
                 f"argsort: lax.sort over (key, validity tag, arange), n={n}",
             ),
             "n": n, "overflow_retries": 0, "counts_sum": n,
@@ -1424,7 +1339,7 @@ class SortEngine:
         # prefix is the pow2 ceiling of the exact cut (capped at P), so
         # nearby cuts share one executable.
         keep_exec = min(P, 1 << int(keep - 1).bit_length())
-        padded_n = ops.bucketed_length(n)
+        padded_n = partition.bucketed_length(n)
         if (
             (x_np.dtype.itemsize == 8 and not x64_enabled())
             or n >= self.host_threshold
@@ -1510,7 +1425,7 @@ class SortEngine:
                 "counts_sum": hinfo["kept_count"],
             }
             return head
-        padded_n = plan.padded_n or ops.bucketed_length(n)
+        padded_n = plan.padded_n or partition.bucketed_length(n)
         capacity = plan.capacity or partition.default_capacity(padded_n, P)
         keep = info["keep_exec"]
         x_pad = np.zeros(padded_n, x_np.dtype)
@@ -1524,11 +1439,8 @@ class SortEngine:
             got = int(jnp.sum(counts))
             if got < kept_total:
                 # A kept bucket overflowed its (kept-only) capacity:
-                # escalate ×2 exactly like sort's retry loop.
-                if capacity >= padded_n:
-                    raise AssertionError("overflow with capacity == padded_n")
-                capacity = min(padded_n, capacity * 2)
-                capacity += (-capacity) % 8
+                # escalate exactly like sort's retry loop.
+                capacity = _grow_capacity(capacity, padded_n)
                 retries += 1
                 continue
             if kept_total < k:
@@ -1555,7 +1467,7 @@ class SortEngine:
                 self.trace_count += 1  # runs at trace time only
                 return _sim_topk_padded(
                     x_pad, n_valid, P=self.topo.total_procs, keep=keep,
-                    capacity=capacity, local_sort=self.local_sort,
+                    capacity=capacity,
                 )
 
             fn = jax.jit(sim_topk)

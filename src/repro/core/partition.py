@@ -17,8 +17,7 @@ balanced to within a provable factor regardless of the value distribution.
 Everything here is pure ``jnp`` and jit-safe.  :func:`scatter_to_buckets`
 places elements with one stable sort by bucket id, with no O(n·P)
 intermediate; :func:`bucket_ranks` / :func:`bucket_counts` keep the one-hot
-formulation, for the MoE dispatch and as the reference of the Pallas
-kernel twin in ``repro.kernels`` (histogram/rank via MXU matmul).
+formulation for the MoE dispatch.
 """
 
 from __future__ import annotations
@@ -56,6 +55,18 @@ def default_capacity(n: int, num_buckets: int) -> int:
     cap = int(-(-2 * n // num_buckets))
     cap += (-cap) % 8
     return cap
+
+
+def bucketed_length(n: int) -> int:
+    """Power-of-two shape bucket for ``n``, at least 128.
+
+    The shared shape-bucketing rule: ``repro.core.engine.SortEngine`` pads
+    inputs to it and keys its warm jit cache on it, so any two lengths in
+    the same bucket reuse one compilation.
+    """
+    # The floor is the TPU's vector lane width: every length up to 128
+    # shares one executable, and no padded row is shorter than a lane.
+    return max(1 << max(int(n) - 1, 0).bit_length(), 128)
 
 
 def pack_segments(
@@ -186,8 +197,7 @@ def bucket_ranks(bucket_ids: jax.Array, num_buckets: int) -> jax.Array:
     """Rank of each element within its bucket (stable, order-of-appearance).
 
     rank[i] = #{j < i : bucket_ids[j] == bucket_ids[i]}.  Implemented as a
-    cumulative sum over the one-hot bucket matrix — the same formulation the
-    Pallas ``partition_kernel`` computes with an MXU matmul.
+    cumulative sum over the one-hot bucket matrix.
     """
     one_hot = jax.nn.one_hot(bucket_ids, num_buckets, dtype=jnp.int32)
     # exclusive cumsum along the element axis

@@ -9,7 +9,7 @@ partition, and the merge-free gather property becomes the contiguous
 (expert, capacity) buffer the grouped FFN matmul wants.
 
 ``dispatch='sorted'`` uses ``repro.core.partition`` bucket counts/ranks
-(the same math as the Pallas ``partition_kernel``) to compute, for every
+(the one-hot histogram + exclusive cumsum) to compute, for every
 assignment, its slot in the (E, C, d) dispatch buffer — histogram + stable
 rank, no data-dependent control flow.  ``dispatch='argsort'`` computes the
 same ranks from ONE stable argsort of the expert ids (position minus

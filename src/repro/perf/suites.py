@@ -1,7 +1,7 @@
 """The gated perf scenarios, one registry per bench suite (DESIGN.md §9).
 
 Each entry mirrors an existing ``benchmarks/`` suite — ``engine``,
-``sortd``, ``kernels``, ``netsim``, ``verify``, ``fleet`` — but pinned to a small,
+``sortd``, ``netsim``, ``verify``, ``fleet`` — but pinned to a small,
 deterministic slice sized for a CI gate: the point is a *stable judged
 number per case*, not figure-quality coverage (that stays in
 ``benchmarks/run.py``).  Every case builds its inputs and warms its
@@ -26,7 +26,7 @@ from repro.perf.normalize import Workload
 from repro.perf.schema import PerfCase
 
 SUITE_NAMES = (
-    "engine", "sortd", "kernels", "netsim", "verify", "fleet", "faults",
+    "engine", "sortd", "netsim", "verify", "fleet", "faults",
     "workloads",
 )
 
@@ -109,125 +109,6 @@ def sortd_cases(*, smoke: bool = True) -> "list[PerfCase]":
             smoke=in_smoke,
         ))
     return out
-
-
-# --- kernels --------------------------------------------------------------
-
-
-def _jnp_sort_setup(n: int):
-    def setup():
-        import jax
-        import jax.numpy as jnp
-
-        from repro.data.distributions import make_array
-
-        f = jax.jit(jnp.sort)
-        x = jnp.asarray(make_array("random", n, seed=n))
-        return lambda: f(x)
-
-    return setup
-
-
-def _local_sort_setup(n: int):
-    def setup():
-        import jax.numpy as jnp
-
-        from repro.data.distributions import make_array
-        from repro.kernels import ops
-
-        x = jnp.asarray(make_array("random", n, seed=n))
-        return lambda: ops.local_sort(x)
-
-    return setup
-
-
-def _rowsort_setup(backend: str, B: int, L: int):
-    """One segment-path row backend on a fixed full-range int32 batch:
-    ``vmap`` jits the vmapped XLA sort, the pallas backends call the fused
-    batched kernel (``repro.kernels.batched``) directly — the backends
-    ``choose_row_backend`` can select."""
-
-    def setup():
-        import jax
-        import jax.numpy as jnp
-
-        from repro.kernels import batched, ops
-
-        rng = np.random.default_rng(L)
-        info = np.iinfo(np.int32)
-        x = jnp.asarray(rng.integers(info.min, info.max, (B, L), dtype=np.int32))
-        if backend == "vmap":
-            f = jax.jit(jax.vmap(jnp.sort))
-            return lambda: f(x)
-        lens = jnp.full((B,), L, jnp.int32)
-        method = {"pallas": "bitonic", "pallas2op": "bitonic2op"}[backend]
-        interpret = ops._auto_interpret(None)
-        return lambda: batched.batched_row_sort(
-            x, lens, method=method, interpret=interpret
-        )
-
-    return setup
-
-
-def kernels_cases(*, smoke: bool = True) -> "list[PerfCase]":
-    # The interpreted Pallas paths cost orders of magnitude more than the
-    # work model and a python-interpreted call swings run to run, so those
-    # cases carry the wide netsim-style band.
-    wide = {"lower": 0.70, "upper": 1.50}
-    cases = [
-        PerfCase(
-            suite="kernels",
-            key="jnp_sort/65536",
-            setup=_jnp_sort_setup(65536),
-            workload=_sort_workload(65536, 4),
-        ),
-        PerfCase(
-            suite="kernels",
-            key="bitonic_interpret/4096",
-            setup=_local_sort_setup(4096),
-            # the ratio still gates, but the python-interpreted call
-            # swings ~2x run to run — wide band
-            workload=_sort_workload(4096, 4),
-            **wide,
-        ),
-        # The row-backend A/B, persisted as paired baseline rows: the committed raw_s ratio documents which
-        # backend wins the B64xL1024 serving bucket on this host, and
-        # perfguard re-judges each side on every gate run
-        # (benchmarks/bench_kernels.py runs the same pair interleaved).
-        PerfCase(
-            suite="kernels",
-            key="rowsort_vmap/B64xL1024",
-            setup=_rowsort_setup("vmap", 64, 1024),
-            workload=_sort_workload(64 * 1024, 4),
-            **wide,
-        ),
-        PerfCase(
-            suite="kernels",
-            key="rowsort_pallas/B64xL1024",
-            setup=_rowsort_setup("pallas", 64, 1024),
-            workload=_sort_workload(64 * 1024, 4),
-            **wide,
-        ),
-    ]
-    if not smoke:
-        cases += [
-            PerfCase(
-                suite="kernels",
-                key="jnp_sort/262144",
-                setup=_jnp_sort_setup(262144),
-                workload=_sort_workload(262144, 4),
-                smoke=False,
-            ),
-            PerfCase(
-                suite="kernels",
-                key="rowsort_pallas2op/B64xL1024",
-                setup=_rowsort_setup("pallas2op", 64, 1024),
-                workload=_sort_workload(64 * 1024, 4),
-                smoke=False,
-                **wide,
-            ),
-        ]
-    return cases
 
 
 # --- netsim ---------------------------------------------------------------
@@ -581,7 +462,6 @@ def verify_cases(*, smoke: bool = True) -> "list[PerfCase]":
 SUITES = {
     "engine": engine_cases,
     "sortd": sortd_cases,
-    "kernels": kernels_cases,
     "netsim": netsim_cases,
     "verify": verify_cases,
     "fleet": fleet_cases,

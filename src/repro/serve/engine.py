@@ -2,7 +2,7 @@
 
 Batch formation uses the paper's technique: requests are **sorted by
 prompt length** with the framework's sort primitive — now routed through
-``repro.core.engine.SortEngine.sort_pairs`` (the bitonic pair-sort kernel
+``repro.core.engine.SortEngine.sort_pairs`` (one multi-operand XLA sort
 behind a power-of-two shape-bucketed jit cache, DESIGN.md §4), so each
 padded prefill batch wastes the minimum number of pad tokens — the
 serving-side face of the Array Division Procedure (DESIGN.md §3) — and a

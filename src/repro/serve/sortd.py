@@ -17,7 +17,7 @@ Mechanics:
   of growing an unbounded backlog.
 * **Adaptive coalescing**: requests bin by ``(dtype, pow2 shape bucket)`` —
   the same bucketing rule as the engine's warm jit cache
-  (``repro.kernels.ops.bucketed_length``), so every flush lands on an
+  (``repro.core.partition.bucketed_length``), so every flush lands on an
   already-compiled executable.  Mixed dtypes are never coalesced (a fused
   batch is one device array), and rows only ever pad within their own
   bucket, which bounds per-batch pad waste below 50% + the deadline's
@@ -81,7 +81,7 @@ import numpy as np
 
 from repro.core import workloads
 from repro.core.engine import SortEngine
-from repro.kernels import ops
+from repro.core.partition import bucketed_length
 
 __all__ = ["Sortd", "SortdConfig", "QueueFull", "WorkerKilled", "affinity_key"]
 
@@ -105,7 +105,7 @@ def affinity_key(arr: np.ndarray) -> "tuple[str, int]":
     the fleet's affinity router — same key ⇒ same bin ⇒ same compiled
     executable ⇒ (in a fleet) same worker.
     """
-    return (str(arr.dtype), ops.bucketed_length(max(arr.size, 1)))
+    return (str(arr.dtype), bucketed_length(max(arr.size, 1)))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -178,9 +178,9 @@ class _BucketStats:
         self.pad_cells = 0
         self.valid_cells = 0
         self.lat_s = collections.deque(maxlen=window)
-        # flush count per executed plan method (e.g. bitonic vs
-        # bitonic_pallas vs bitonic2op) — names the kernel the engine
-        # actually ran for this bucket's traffic
+        # flush count per executed plan method (bitonic rows, or the
+        # paper/sampled bucket path) — names what the engine actually ran
+        # for this bucket's traffic
         self.methods: dict[str, int] = {}
 
 

@@ -27,7 +27,9 @@ from typing import Callable, Sequence
 
 import numpy as np
 
-from repro.core import OHHCTopology, SortEngine, SortPlan, autotune_capacity
+from repro.core import (
+    OHHCTopology, SortEngine, SortPlan, autotune_capacity, bucketed_length,
+)
 from repro.verify.grid import (
     FAULT_IMPOSSIBLE,
     FaultCell,
@@ -126,10 +128,8 @@ def forced_plan(eng: SortEngine, sc: Scenario, x: np.ndarray) -> SortPlan:
         return SortPlan("host", sc.method, None, None, "verify grid")
     if sc.path == "dist":
         return SortPlan("dist", sc.method, None, None, "verify grid")
-    from repro.kernels import ops
-
     stats = eng.stats(x)
-    padded = ops.bucketed_length(x.size)
+    padded = bucketed_length(x.size)
     cap = autotune_capacity(stats, sc.method, eng.topo.total_procs, padded)
     return SortPlan("sim", sc.method, cap, padded, "verify grid")
 
@@ -185,15 +185,12 @@ def run_segment_scenario(
     """One segmented-batch cell: force the row-sort method through
     ``sort_segments(plan=...)`` and oracle every row against ``np.sort``.
 
-    The stored output is the concatenation of the sorted segments, so the
-    cross-check asserts byte-agreement between the vmapped XLA backend and
-    both fused Pallas variants on the same batch.
+    The stored output is the concatenation of the sorted segments, which
+    the drift baseline fingerprints.
     """
-    from repro.kernels import ops
-
     flat, lens = sc.make_batch()
     eng = engines.segment_engine()
-    padded_n = ops.bucketed_length(max(lens) if lens else 1)
+    padded_n = bucketed_length(max(lens) if lens else 1)
     plan = SortPlan("sim", sc.method, None, padded_n, "verify segment grid")
     t0 = time.perf_counter()
     try:

@@ -218,14 +218,13 @@ def tier1_grid() -> list[Scenario]:
 # ---------------------------------------------------------------- segments
 # The segmented-batch twin of the grid above: one SegmentScenario is one
 # forced (row-sort method × dtype × row class × length mix) cell of the
-# ``sort_segments`` hot path, covering every row backend the engine's
-# autotune can pick (vmapped XLA and both fused Pallas variants) so the
-# drift baseline owns the batched kernel too (DESIGN.md §7, §8).
+# ``sort_segments`` hot path, so the drift baseline owns the batched row
+# sort too (DESIGN.md §7, §8).
 
-SEGMENT_METHODS = ("bitonic", "bitonic_pallas", "bitonic2op")
+SEGMENT_METHODS = ("bitonic",)
 
 # Row classes: uniform keys, dtype-max sentinel-tie mixes (the pad-collision
-# class the tagged kernels exist for), all-equal rows, reversed ramps.
+# class: real keys equal to the pad fill), all-equal rows, reversed ramps.
 SEGMENT_ROW_CLASSES = ("random", "ties", "equal", "ramp")
 
 # Longest-row values straddling pow2 shape buckets (128 and 1024).
@@ -311,14 +310,14 @@ def segment_smoke_grid() -> "list[SegmentScenario]":
 
 
 def segment_tier1_grid() -> "list[SegmentScenario]":
-    """Fast pytest subset: every method and row class at one size each."""
+    """Fast pytest subset: every row class and dtype at one size each."""
     picked = [
         SegmentScenario("bitonic", "int32", "random", 100),
-        SegmentScenario("bitonic_pallas", "int32", "ties", 100),
-        SegmentScenario("bitonic_pallas", "uint32", "random", 1000),
-        SegmentScenario("bitonic2op", "int32", "equal", 1000),
-        SegmentScenario("bitonic2op", "uint32", "ties", 100),
-        SegmentScenario("bitonic_pallas", "float32", "ramp", 1000),
+        SegmentScenario("bitonic", "int32", "ties", 100),
+        SegmentScenario("bitonic", "uint32", "random", 1000),
+        SegmentScenario("bitonic", "int32", "equal", 1000),
+        SegmentScenario("bitonic", "uint32", "ties", 100),
+        SegmentScenario("bitonic", "float32", "ramp", 1000),
     ]
     smoke_ids = {sc.scenario_id for sc in segment_smoke_grid()}
     return [sc for sc in picked if sc.scenario_id in smoke_ids]

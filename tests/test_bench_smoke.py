@@ -33,7 +33,6 @@ SUITE_NAMES = (
     "efficiency_half",
     "counters",
     "commsteps",
-    "kernels",
     "moe_dispatch",
     "engine",
     "netsim",

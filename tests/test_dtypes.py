@@ -110,10 +110,9 @@ def test_engine_sim_handles_negative_spans(dtype, method):
     x = rng.integers(info.min, info.max, 1500, dtype=np.int64).astype(dtype)
     eng = SortEngine(TOPO)
     stats = eng.stats(x)
-    from repro.core import SortPlan, autotune_capacity
-    from repro.kernels import ops
+    from repro.core import SortPlan, autotune_capacity, bucketed_length
 
-    padded = ops.bucketed_length(x.size)
+    padded = bucketed_length(x.size)
     cap = autotune_capacity(stats, method, TOPO.total_procs, padded)
     out = eng.sort(x, plan=SortPlan("sim", method, cap, padded, "forced"))
     np.testing.assert_array_equal(out, np.sort(x))
@@ -159,8 +158,9 @@ _X64_SCRIPT = r"""
 import os
 os.environ["JAX_ENABLE_X64"] = "1"
 import numpy as np
-from repro.core import OHHCTopology, SortEngine, SortPlan, autotune_capacity, x64_enabled
-from repro.kernels import ops
+from repro.core import (
+    OHHCTopology, SortEngine, SortPlan, autotune_capacity, bucketed_length, x64_enabled,
+)
 
 assert x64_enabled()
 topo = OHHCTopology(1, "full")
@@ -170,7 +170,7 @@ eng = SortEngine(topo)
 x = (np.int64(1) << 60) + np.arange(36 * 64, dtype=np.int64)
 rng = np.random.default_rng(2); rng.shuffle(x)
 stats = eng.stats(x)
-padded = ops.bucketed_length(x.size)
+padded = bucketed_length(x.size)
 cap = autotune_capacity(stats, "paper", topo.total_procs, padded)
 out = eng.sort(x, plan=SortPlan("sim", "paper", cap, padded, "forced"))
 assert out.dtype == np.int64, out.dtype

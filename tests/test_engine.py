@@ -13,6 +13,7 @@ from repro.core import (
     SortEngine,
     SortPlan,
     autotune_capacity,
+    bucketed_length,
     choose_plan,
     default_capacity,
     estimate_stats,
@@ -20,6 +21,25 @@ from repro.core import (
 from repro.data.distributions import ALL_DISTRIBUTIONS, make_array
 
 TOPO = OHHCTopology(1, "full")  # P = 36
+
+# The key dtypes the segment and pairs tests sweep.
+ROW_DTYPES = (np.int32, np.uint32, np.int16, np.float32)
+TIE_DTYPES = (np.int32, np.uint32, np.int16)
+
+
+@pytest.fixture(scope="module")
+def engine():
+    """One engine for the sweeps, so each shape bucket compiles once."""
+    return SortEngine(TOPO)
+
+
+def random_keys(rng, n, dtype):
+    """``n`` keys over the dtype's whole range (normal draws for floats)."""
+    dtype = np.dtype(dtype)
+    if np.issubdtype(dtype, np.integer):
+        info = np.iinfo(dtype)
+        return rng.integers(info.min, info.max, n, dtype=dtype, endpoint=True)
+    return rng.normal(size=n).astype(dtype)
 
 
 def mk_stats(
@@ -147,6 +167,24 @@ def test_engine_sort_correct_no_overflow(dist):
     assert eng.last_report["counts_sum"] == x.size
 
 
+@pytest.mark.parametrize("dtype", [np.int8, np.int16, np.int32, np.uint32, np.float32])
+@pytest.mark.parametrize("n", [1, 2, 100, 127, 128, 129, 512, 1000, 4096, 10000])
+def test_sort_shape_bucket_boundaries(n, dtype, engine, rng):
+    """Lengths on both sides of the 128 floor and of pow2 shape buckets,
+    over the whole range of each key dtype."""
+    x = random_keys(rng, n, dtype)
+    out = engine.sort(x)
+    assert out.dtype == x.dtype
+    np.testing.assert_array_equal(out, np.sort(x))
+
+
+@given(n=st.integers(1, 3000), seed=st.integers(0, 100))
+@settings(max_examples=15, deadline=None)
+def test_sort_duplicate_heavy_property(n, seed, engine):
+    x = np.random.default_rng(seed).integers(0, 50, n).astype(np.int32)
+    np.testing.assert_array_equal(engine.sort(x), np.sort(x))
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize("dist", ALL_DISTRIBUTIONS)
 def test_engine_sort_correct_1e6(dist):
@@ -173,9 +211,7 @@ def test_autotuned_capacity_property(n, seed, dist, method):
     stats = eng.stats(x)
     plan = choose_plan(stats, TOPO)
     if plan.path != "sim" or plan.method != method:
-        from repro.kernels import ops
-
-        padded = ops.bucketed_length(n)
+        padded = bucketed_length(n)
         cap = autotune_capacity(stats, method, TOPO.total_procs, padded)
         plan = SortPlan("sim", method, cap, padded, "forced")
     out = eng.sort(x, plan=plan)
@@ -393,90 +429,107 @@ def test_batch_plan_policy_bitonic_vs_bucket():
         choose_batch_plan(None, 36, big)
 
 
-def test_batch_plan_row_backend_mapping():
-    from repro.core import ROW_BACKENDS, choose_batch_plan
+def test_segment_rows_plan_bitonic_without_compiling():
+    from repro.core import choose_batch_plan
 
-    want = {"vmap": "bitonic", "pallas": "bitonic_pallas", "pallas2op": "bitonic2op"}
-    for backend in ROW_BACKENDS:
-        p = choose_batch_plan(None, 36, 1024, row_backend=backend)
-        assert p.method == want[backend]
-        assert p.capacity is None
-        assert f"row_backend={backend}" in p.reason
-    with pytest.raises(ValueError, match="row_backend"):
-        choose_batch_plan(None, 36, 1024, row_backend="cuda")
-
-
-def test_choose_row_backend_env_and_probe(monkeypatch):
-    from repro.core import ROW_BACKENDS, choose_row_backend
-
-    # env override forces a backend, including the Pallas kernels
-    for forced in ROW_BACKENDS:
-        monkeypatch.setenv("REPRO_ROW_BACKEND", forced)
-        backend, detail = choose_row_backend()
-        assert backend == forced and "forced" in detail
-    monkeypatch.setenv("REPRO_ROW_BACKEND", "cuda")
-    with pytest.raises(ValueError, match="REPRO_ROW_BACKEND"):
-        choose_row_backend()
-    # the default is vmap (XLA's sort) with no probe: nothing runs, nothing
-    # is compiled, and the Pallas kernels are never a default
-    monkeypatch.delenv("REPRO_ROW_BACKEND", raising=False)
-    assert choose_row_backend() == ("vmap", "row_backend=vmap (default)")
+    p = choose_batch_plan(None, 36, 1024)
+    assert (p.method, p.capacity) == ("bitonic", None)
     eng = SortEngine(TOPO)
     plan = eng.plan_segments(np.arange(300, dtype=np.int32), [100, 200])
-    assert plan.method == "bitonic" and eng.trace_count == 0
-    assert "row_backend=vmap (default)" in plan.reason
+    assert (plan.method, plan.capacity) == ("bitonic", None)
+    assert eng.trace_count == 0
 
 
-def test_sort_segments_pallas_backends(monkeypatch):
-    # forcing each backend through the env knob must route sort_segments
-    # through the fused kernel and stay oracle-exact, with the method
-    # visible in last_report (what sortd's metrics surface per bucket)
-    rng = np.random.default_rng(5)
-    lens = [0, 1, 100, 513, 1000]
-    arrs = [rng.integers(0, 1 << 30, n).astype(np.int32) for n in lens]
-    flat = np.concatenate(arrs)
-    for backend, method in (
-        ("pallas", "bitonic_pallas"), ("pallas2op", "bitonic2op")
-    ):
-        monkeypatch.setenv("REPRO_ROW_BACKEND", backend)
-        eng = SortEngine(TOPO)
-        outs = eng.sort_segments(flat, lens)
+@pytest.mark.parametrize("dtype", ROW_DTYPES)
+def test_sort_segments_bucket_straddle(dtype, engine, rng):
+    """Batches whose longest row sits on either side of the 128 and 1024
+    shape buckets, each with an empty and a length-1 row."""
+    for lens in ([0, 1, 127, 128], [0, 1, 127, 128, 129],
+                 [0, 1, 1000, 1023, 1024], [0, 1, 1024, 1025]):
+        arrs = [random_keys(rng, n, dtype) for n in lens]
+        outs = engine.sort_segments(np.concatenate(arrs), lens)
+        for a, o in zip(arrs, outs):
+            assert o.dtype == a.dtype
+            np.testing.assert_array_equal(o, np.sort(a))
+        plan = engine.last_report["plan"]
+        assert (plan.method, plan.padded_n) == ("bitonic", bucketed_length(max(lens)))
+
+
+@pytest.mark.parametrize("dtype", ROW_DTYPES)
+def test_sort_segments_across_bitonic_max(dtype, engine, rng):
+    """A batch plans on its longest row: 8192 keys is the last bitonic
+    row bucket, one more key sends the whole batch to the bucket path."""
+    from repro.core import SEGMENT_BITONIC_MAX
+
+    assert SEGMENT_BITONIC_MAX == 8192
+    for longest, method_is_bitonic in ((8192, True), (8193, False)):
+        lens = [0, 1, 300, longest]
+        arrs = [random_keys(rng, n, dtype) for n in lens]
+        outs = engine.sort_segments(np.concatenate(arrs), lens)
         for a, o in zip(arrs, outs):
             np.testing.assert_array_equal(o, np.sort(a))
-        assert eng.last_report["plan"].method == method
-        assert f"row_backend={backend}" in eng.last_report["plan"].reason
-        assert eng.last_report["overflow_retries"] == 0
+        plan = engine.last_report["plan"]
+        assert (plan.method == "bitonic") is method_is_bitonic
+        assert (plan.capacity is None) is method_is_bitonic
+        assert engine.last_report["overflow_retries"] == 0
 
 
-def test_sort_segments_sentinel_tie_rows(monkeypatch):
-    # dtype-max keys across every row backend: the valid prefix must keep
-    # exactly seg_len sentinels per row (lost-element regression guard)
-    hi = np.iinfo(np.int32).max
-    rng = np.random.default_rng(9)
+@pytest.mark.parametrize("dtype", TIE_DTYPES)
+def test_sort_segments_sentinel_tie_rows(dtype, rng):
+    # dtype-max keys: the valid prefix must keep exactly seg_len sentinels
+    # per row (lost-element regression guard), beside an all-equal row
+    hi = np.iinfo(dtype).max
     arrs = [
-        np.full(300, hi, np.int32),
-        np.where(rng.random(777) < 0.5, hi, hi - 1).astype(np.int32),
+        np.full(300, hi, dtype),
+        np.full(77, 42, dtype),
+        np.where(rng.random(777) < 0.5, hi, hi - 1).astype(dtype),
     ]
-    flat = np.concatenate(arrs)
-    for backend in ("vmap", "pallas", "pallas2op"):
-        monkeypatch.setenv("REPRO_ROW_BACKEND", backend)
-        eng = SortEngine(TOPO)
-        outs = eng.sort_segments(flat, [a.size for a in arrs])
-        for a, o in zip(arrs, outs):
-            np.testing.assert_array_equal(o, np.sort(a))
+    eng = SortEngine(TOPO)
+    outs = eng.sort_segments(np.concatenate(arrs), [a.size for a in arrs])
+    for a, o in zip(arrs, outs):
+        np.testing.assert_array_equal(o, np.sort(a))
 
 
-def test_sort_pairs_sentinel_ties_engine():
+@given(seed=st.integers(0, 1000), lbits=st.integers(7, 12))
+@settings(max_examples=10, deadline=None)
+def test_sort_segments_property(seed, lbits, engine):
+    # random (B ≤ 8, L = 2^7…2^12) batches vs the per-row oracle
+    rng = np.random.default_rng(seed)
+    L = 1 << lbits
+    B = int(rng.integers(1, 9))
+    lens = rng.integers(0, L + 1, B)
+    lens[0] = L  # the batch's bucket is L
+    flat = rng.integers(0, 1 << 30, int(lens.sum())).astype(np.int32)
+    outs = engine.sort_segments(flat, lens)
+    for o, seg in zip(outs, np.split(flat, np.cumsum(lens)[:-1])):
+        np.testing.assert_array_equal(o, np.sort(seg))
+
+
+@pytest.mark.parametrize("dtype", TIE_DTYPES)
+@pytest.mark.parametrize("n", [10, 100, 129, 1000])
+def test_sort_pairs_sentinel_ties_engine(n, dtype, engine, rng):
     # engine.sort_pairs pre-pads to the shape bucket before the traced fn;
     # the traced n_valid must keep pad zeros from displacing real payloads
-    eng = SortEngine(TOPO)
-    hi = np.iinfo(np.int32).max
-    k = np.full(200, hi, np.int32)
-    k[::3] = hi - 1
-    v = np.arange(1, 201, dtype=np.int32)
-    ks, vs = eng.sort_pairs(k, v)
+    hi = np.iinfo(dtype).max
+    k = np.full(n, hi, dtype)
+    k[rng.random(n) < 0.5] = hi - 1  # mix of max and near-max keys
+    v = np.arange(1, n + 1, dtype=np.int32)  # payloads, none zero
+    ks, vs = engine.sort_pairs(k, v)
     np.testing.assert_array_equal(np.asarray(ks), np.sort(k))
     np.testing.assert_array_equal(np.sort(np.asarray(vs)), v)
+
+
+@pytest.mark.parametrize("dtype", [np.int32, np.uint32])
+@pytest.mark.parametrize("n", [10, 128, 1000, 5000])
+def test_sort_pairs_payloads(n, dtype, engine, rng):
+    # duplicate-heavy keys; each payload is its key's index, so a payload
+    # that left its key shows as keys[payload] != sorted key
+    keys = rng.integers(0, 64, n).astype(dtype)
+    ks, vs = engine.sort_pairs(keys, np.arange(n, dtype=np.int32))
+    ks, vs = np.asarray(ks), np.asarray(vs)
+    np.testing.assert_array_equal(ks, np.sort(keys))
+    np.testing.assert_array_equal(np.sort(vs), np.arange(n))
+    np.testing.assert_array_equal(keys[vs], ks)
 
 
 def test_estimate_batch_stats_worst_row_scaled():

@@ -113,8 +113,6 @@ def expected_module(key):
         return {"pairs": "jit_pairs_sort", "topk": "jit_sim_topk", "dist": "jit_dist_sort"}[key[0]]
     if key[3] == "bitonic":
         return "jit_row_sort"
-    if key[3] in ("bitonic_pallas", "bitonic2op"):
-        return "jit_batch_row_sort"
     return "jit_sim_sort"
 
 
@@ -144,15 +142,13 @@ def test_every_cached_executable_has_its_stable_name():
     x = keys()
     eng.sort(x)  # sim paper
     eng.sort_segments(x[:300], [100, 200])  # bitonic rows
-    eng.sort_segments(x[:300], [100, 200],
-                      plan=SortPlan("sim", "bitonic_pallas", None, 256, "forced pallas"))
     eng.sort_segments(keys(2 * 9000), [9000, 9000])  # bucket rows, vmapped
     eng.sort_pairs(x, np.arange(N, dtype=np.int32))
     eng.top_k(x, N // 2)
     kinds = {(k[0], expected_module(k)) for k in eng._fn_cache}
     assert kinds == {("sim", "jit_sim_sort"), ("batch", "jit_row_sort"),
-                     ("batch", "jit_batch_row_sort"), ("batch", "jit_sim_sort"),
-                     ("pairs", "jit_pairs_sort"), ("topk", "jit_sim_topk")}
+                     ("batch", "jit_sim_sort"), ("pairs", "jit_pairs_sort"),
+                     ("topk", "jit_sim_topk")}
     for key, fn in eng._fn_cache.items():
         assert module_name(fn, lowering_args(key)) == expected_module(key), key
 

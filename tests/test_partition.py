@@ -83,7 +83,7 @@ def test_sim_sort_builds_no_rank_matrix(method):
     n_pad, P = 4096, 36
     fn = functools.partial(
         _sim_sort_padded, P=P, capacity=partition.default_capacity(n_pad, P),
-        method=method, sample_size=2048, local_sort=jnp.sort,
+        method=method, sample_size=2048,
     )
     jaxpr = jax.make_jaxpr(fn)(jnp.zeros(n_pad, jnp.int32), jnp.int32(n_pad))
     assert max(_intermediate_sizes(jaxpr.jaxpr)) < n_pad * (P + 1)

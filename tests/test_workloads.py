@@ -134,7 +134,7 @@ def test_top_k_plan_does_not_inherit_full_sort_capacity():
     full sort's worst-row capacity to cover that bucket, but a k=64 head
     never touches it — the top-k plan must size capacity from the KEPT
     buckets only and still run overflow-free."""
-    from repro.kernels import ops
+    from repro.core import bucketed_length
 
     x = np.concatenate(
         [
@@ -145,7 +145,7 @@ def test_top_k_plan_does_not_inherit_full_sort_capacity():
     np.random.default_rng(8).shuffle(x)
     stats = ENG.stats(x)
     cap_full = autotune_capacity(
-        stats, "paper", P, ops.bucketed_length(x.size)
+        stats, "paper", P, bucketed_length(x.size)
     )
     assert cap_full >= 1448  # the dupe bucket dominates the full sort
 

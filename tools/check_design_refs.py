@@ -9,8 +9,8 @@ Three checks, all exiting non-zero with a listing on failure:
 2. **Symbol coverage**: every section in ``SYMBOL_SECTIONS`` must mention
    the full public surface it owns — the module's ``__all__`` (parsed
    with ``ast``, so new exports automatically demand coverage) plus
-   listed extras.  Currently §2 ↔ ``repro.kernels.batched`` (fused
-   batched row sort), §8 ↔ ``repro.serve.sortd`` (serving layer),
+   listed extras.  Currently §2 ↔ ``repro.core.dist_sort`` (sharded
+   output, XLA local sort, shape buckets), §8 ↔ ``repro.serve.sortd`` (serving layer),
    §9 ↔ ``repro.perf`` (perf gate), §10 ↔ ``repro.serve.fleet``
    (multi-worker serving), §11 ↔ ``repro.net.faults`` (degraded
    serving), and §12 ↔ ``repro.core.workloads`` (engine workload ops).
@@ -51,11 +51,12 @@ MD_GLOBS = ("docs/*.md",)
 # export without documentation fails this check) plus the listed extras.
 SYMBOL_SECTIONS = {
     2: (
-        "src/repro/kernels/batched.py",  # fused batched row sort
+        "src/repro/core/dist_sort.py",  # sharded output, local sort
         (
-            "local_sort_pairs",
-            "sort_pairs_tile_tagged",
-            "bucket_count_rank",
+            "scatter_to_buckets",
+            "bucketed_length",
+            "sort_pairs",
+            "argsort_keys",
         ),
     ),
     8: (
@@ -69,9 +70,6 @@ SYMBOL_SECTIONS = {
             "SEGMENT_BITONIC_MAX",
             "pack_segments",
             "unpack_segments",
-            "ROW_BACKENDS",
-            "choose_row_backend",
-            "REPRO_ROW_BACKEND",
             "SegmentScenario",
         ),
     ),
