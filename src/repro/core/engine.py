@@ -98,8 +98,9 @@ SPAN_HOST_SORT = "sort_engine.host_sort"
 """``jax.profiler.TraceAnnotation`` names, plain strings with no
 ``#key=value`` arguments.  ``SPAN_SORT`` covers the whole of ``sort`` on
 every path; the stages nest inside it on the calling thread:
-``SPAN_PLAN`` (stats, plan, fault ladder), ``SPAN_PAD`` (the sim pad
-buffer, the dist shard-divisibility pad), ``SPAN_H2D``, one
+``SPAN_PLAN`` (stats, plan, fault ladder), ``SPAN_PAD`` (sim: the take
+of a staging buffer from the engine's pool, the copy of the keys and the
+zeroing of the tail; dist: the shard-divisibility pad), ``SPAN_H2D``, one
 ``SPAN_EXECUTE`` per attempt (dispatch plus the counts sync that waits
 for it, so an overflow retry shows as a second span), ``SPAN_D2H``,
 ``SPAN_UNPACK`` (dist) and ``SPAN_HOST_SORT`` (host path).  They record
@@ -670,6 +671,17 @@ class SortEngine:
                      an impossible scenario rewrites plans onto the healthy
                      host path — results stay exact either way.  Switch at
                      runtime with :meth:`set_fault_scenario`.
+
+    The sim path (``sort`` and ``top_k``) stages each input in a host
+    buffer of its shape bucket, taken from a pool the engine keeps: at
+    most one idle buffer per ``(padded_n, dtype)``, so one buffer per
+    power-of-two bucket in use, under twice the largest bucket's bytes
+    per dtype (64 MiB at the 2^24 int32 bucket).  A fresh ``np.zeros``
+    of that size would be a fresh mapping, and the copy into it
+    first-touch page faults.  The keys go to ``[:n]`` and zeros to the
+    tail, so the executable reads the same bytes as from a fresh buffer.
+    Padding on the device instead would take an input of shape ``(n,)``:
+    one executable per distinct ``n``, not per bucket.
     """
 
     def __init__(
@@ -700,6 +712,9 @@ class SortEngine:
         # The dist path's join writers, one per shard, made on first use.
         self._join_pool: ThreadPoolExecutor | None = None
         self._join_pool_lock = threading.Lock()
+        # The sim path's idle staging buffers, one per (padded_n, dtype).
+        self._pad_pool: dict[tuple[int, np.dtype], np.ndarray] = {}
+        self._pad_pool_lock = threading.Lock()
 
     # ---------------------------------------------------------------- faults
     def set_fault_scenario(self, scenario) -> None:
@@ -964,8 +979,7 @@ class SortEngine:
         padded_n = plan.padded_n or partition.bucketed_length(n)
         capacity = plan.capacity or partition.default_capacity(padded_n, self.topo.total_procs)
         with jax.profiler.TraceAnnotation(SPAN_PAD):
-            x_pad = np.zeros(padded_n, x_np.dtype)
-            x_pad[:n] = x_np
+            x_pad, reused = self._stage_pad(x_np, padded_n)
         with jax.profiler.TraceAnnotation(SPAN_H2D):
             xj = jnp.asarray(x_pad)
         retries = 0
@@ -982,11 +996,35 @@ class SortEngine:
         with jax.profiler.TraceAnnotation(SPAN_D2H):
             out = np.asarray(out)[:n]
             counts = np.asarray(counts)
+        self._release_pad(x_pad)
         self.last_report = {
             "plan": plan, "n": n, "stats": stats, "capacity_used": capacity,
             "counts_sum": got, "overflow_retries": retries, "counts": counts,
+            "pad_reused": reused,
         }
         return out
+
+    def _stage_pad(self, x_np: np.ndarray, padded_n: int) -> tuple[np.ndarray, bool]:
+        """``x_np`` padded with zeros to ``padded_n``, in a buffer taken from
+        the pool, and whether the pool had one (else it is new).  The caller
+        owns the buffer until it hands it back with :meth:`_release_pad`,
+        once the executable that reads it has finished; a caller that
+        raises first never hands it back, and the buffer is dropped."""
+        with self._pad_pool_lock:
+            x_pad = self._pad_pool.pop((padded_n, x_np.dtype), None)
+        reused = x_pad is not None
+        if not reused:
+            x_pad = np.empty(padded_n, x_np.dtype)
+        n = x_np.size
+        x_pad[:n] = x_np
+        x_pad[n:] = 0
+        return x_pad, reused
+
+    def _release_pad(self, x_pad: np.ndarray) -> None:
+        """Return a staging buffer to the pool, unless it already holds an
+        idle one of that shape and dtype."""
+        with self._pad_pool_lock:
+            self._pad_pool.setdefault((x_pad.size, x_pad.dtype), x_pad)
 
     # --------------------------------------------------------------- batched
     def plan_segments(self, keys, seg_lens) -> SortPlan:
@@ -1428,8 +1466,7 @@ class SortEngine:
         padded_n = plan.padded_n or partition.bucketed_length(n)
         capacity = plan.capacity or partition.default_capacity(padded_n, P)
         keep = info["keep_exec"]
-        x_pad = np.zeros(padded_n, x_np.dtype)
-        x_pad[:n] = x_np
+        x_pad, reused = self._stage_pad(x_np, padded_n)
         xj = jnp.asarray(x_pad)
         retries = 0
         while True:
@@ -1451,13 +1488,15 @@ class SortEngine:
                 retries += 1
                 continue
             break
+        head = np.asarray(head_pad)[:k]
+        self._release_pad(x_pad)
         self.last_report = {
             "plan": plan, "n": n, "k": k, "capacity_used": capacity,
             "skipped_buckets": P - keep, "kept_count": kept_total,
             "counts_sum": got, "overflow_retries": retries,
-            "counts": np.asarray(counts),
+            "counts": np.asarray(counts), "pad_reused": reused,
         }
-        return np.asarray(head_pad)[:k]
+        return head
 
     def _get_topk_fn(self, padded_n: int, capacity: int, keep: int, dtype):
         key = ("topk", padded_n, capacity, keep, str(dtype))

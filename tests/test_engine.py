@@ -2,6 +2,10 @@
 jit cache (no recompiles within a shape bucket), batched entry points."""
 
 import dataclasses
+import os
+import sys
+import threading
+from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 import pytest
@@ -576,3 +580,159 @@ def test_pack_unpack_segments_roundtrip_and_errors():
         pack_segments(np.arange(9, dtype=np.int32), [9], 8)
     with pytest.raises(ValueError, match="sum"):
         pack_segments(np.arange(9, dtype=np.int32), [4, 4], 8)
+
+
+# ------------------------------------------------------ pad staging pool
+POOL_BUCKET = 4096
+POOL_DTYPES = (np.int32, np.uint32, np.float32)
+# op -> (the engine's getter of its sim executables, the call, its answer)
+POOL_OPS = {
+    "sort": ("_get_sim_fn", lambda eng, x: eng.sort(x), np.sort),
+    "top_k": ("_get_topk_fn", lambda eng, x: eng.top_k(x, x.size // 2),
+              lambda x: np.sort(x)[: x.size // 2]),
+}
+
+
+def bucket_sequence(dtype, rng):
+    """Three inputs of one shape bucket, in order: a large ``n`` of large
+    keys, a smaller ``n`` of small keys, then ``n == padded_n``."""
+    dtype = np.dtype(dtype)
+    if dtype.kind == "f":
+        large = rng.uniform(1e6, 1e7, 4000).astype(dtype)
+        small = rng.uniform(1.0, 100.0, 2100).astype(dtype)
+    else:
+        hi = int(np.iinfo(dtype).max)
+        large = rng.integers(hi // 2, hi, 4000, endpoint=True).astype(dtype)
+        small = rng.integers(1, 100, 2100).astype(dtype)
+    xs = [large, small, random_keys(rng, POOL_BUCKET, dtype)]
+    assert {bucketed_length(x.size) for x in xs} == {POOL_BUCKET}
+    return xs
+
+
+def spy_executable_inputs(eng, getter):
+    """Make ``eng``'s executables from ``getter`` record a host copy of the
+    padded input they are called with; return the list they append to."""
+    seen = []
+    get = getattr(eng, getter)
+
+    def spying_get(*args):
+        fn = get(*args)
+
+        def call(x_pad, *rest):
+            seen.append(np.array(x_pad))
+            return fn(x_pad, *rest)
+
+        return call
+
+    setattr(eng, getter, spying_get)
+    return seen
+
+
+def assert_exact(out, want):
+    assert out.dtype == want.dtype
+    assert out.tobytes() == want.tobytes()
+
+
+@pytest.mark.parametrize("dtype", POOL_DTYPES)
+@pytest.mark.parametrize("op", sorted(POOL_OPS))
+def test_pad_buffer_reused_within_a_bucket_sends_fresh_bytes(op, dtype, rng):
+    getter, run, answer = POOL_OPS[op]
+    eng = SortEngine(TOPO)
+    seen = spy_executable_inputs(eng, getter)
+    for i, x in enumerate(bucket_sequence(dtype, rng)):
+        assert_exact(run(eng, x), answer(x))
+        assert eng.last_report["plan"].path == "sim"
+        assert eng.last_report["pad_reused"] is (i > 0)
+        # what reached the device: the keys, then zeros, as from np.zeros
+        staged = np.concatenate([x, np.zeros(POOL_BUCKET - x.size, x.dtype)])
+        assert seen[-1].dtype == staged.dtype
+        assert seen[-1].tobytes() == staged.tobytes()
+    assert list(eng._pad_pool) == [(POOL_BUCKET, np.dtype(dtype))]
+
+
+@pytest.mark.parametrize("op", sorted(POOL_OPS))
+def test_pad_pool_two_threads_on_one_engine_stay_exact(op):
+    getter, run, answer = POOL_OPS[op]
+    eng = SortEngine(TOPO)
+    start = threading.Barrier(2)
+
+    def caller(seed):
+        xs = bucket_sequence(np.int32, np.random.default_rng(seed))
+        start.wait()
+        for i in range(12):
+            x = xs[i % len(xs)]
+            assert_exact(run(eng, x), answer(x))
+
+    with ThreadPoolExecutor(2) as pool:
+        for f in [pool.submit(caller, seed) for seed in (1, 2)]:
+            f.result(timeout=300)
+    assert list(eng._pad_pool) == [(POOL_BUCKET, np.dtype(np.int32))]
+    (buf,) = eng._pad_pool.values()
+    assert buf.shape == (POOL_BUCKET,) and buf.dtype == np.int32
+
+
+def test_pad_pool_never_hands_one_buffer_to_two_holders():
+    # More takers than cores, switching threads often: a buffer handed to
+    # two holders at once shows as another holder's id inside it.
+    eng = SortEngine(TOPO)
+    workers = (os.cpu_count() or 1) + 2
+    start = threading.Barrier(workers)
+
+    def taker(tag):
+        x = np.full(1000, tag, np.int32)
+        start.wait()
+        for _ in range(200):
+            buf, _ = eng._stage_pad(x, 1024)
+            assert np.all(buf[:1000] == tag) and not buf[1000:].any()
+            eng._release_pad(buf)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        with ThreadPoolExecutor(workers) as pool:
+            for f in [pool.submit(taker, tag) for tag in range(1, workers + 1)]:
+                f.result(timeout=120)
+    finally:
+        sys.setswitchinterval(interval)
+    assert len(eng._pad_pool) == 1
+
+
+def test_pad_pool_keeps_one_idle_buffer_per_bucket_and_dtype():
+    eng = SortEngine(TOPO)
+    x = np.arange(1000, dtype=np.int32)
+    a, a_reused = eng._stage_pad(x, 1024)
+    b, b_reused = eng._stage_pad(x, 1024)  # a is still held: b is new
+    assert not a_reused and not b_reused and a is not b
+    eng._release_pad(a)
+    eng._release_pad(b)
+    assert list(eng._pad_pool.values()) == [a]
+    f, f_reused = eng._stage_pad(x.astype(np.float32), 1024)  # another dtype
+    g, g_reused = eng._stage_pad(x, 2048)  # another bucket
+    assert not f_reused and not g_reused
+    c, c_reused = eng._stage_pad(x[:10], 1024)
+    assert c_reused and c is a and not eng._pad_pool
+    assert_exact(c, np.concatenate([x[:10], np.zeros(1014, np.int32)]))
+    for buf in (f, g, c):
+        eng._release_pad(buf)
+    assert sorted(eng._pad_pool) == [(1024, np.dtype(np.float32)), (1024, np.dtype(np.int32)),
+                                     (2048, np.dtype(np.int32))]
+
+
+@pytest.mark.parametrize("op", sorted(POOL_OPS))
+def test_a_failed_call_drops_its_pad_buffer(op, rng):
+    getter, run, answer = POOL_OPS[op]
+    eng = SortEngine(TOPO)
+    x = bucket_sequence(np.int32, rng)[0]
+    run(eng, x)
+    assert len(eng._pad_pool) == 1
+
+    def lost(*args):
+        raise RuntimeError("executable lost")
+
+    setattr(eng, getter, lost)
+    with pytest.raises(RuntimeError, match="lost"):
+        run(eng, x)
+    assert not eng._pad_pool
+    delattr(eng, getter)
+    assert_exact(run(eng, x), answer(x))
+    assert eng.last_report["pad_reused"] is False

@@ -71,6 +71,20 @@ def test_sim_path_spans_nest_in_order(tmp_path):
     assert stages_of_one_call(trace) == SIM_STAGES
 
 
+@pytest.mark.parametrize("warm", [False, True], ids=["first", "reused"])
+def test_sim_pad_is_one_plain_span_with_a_first_or_reused_buffer(tmp_path, warm):
+    eng = SortEngine()
+    if warm:
+        eng.sort(keys(N - 7, seed=1))  # leaves the bucket's buffer in the pool
+    x = keys()
+    out, trace = profiled(tmp_path, lambda: eng.sort(x))
+    assert np.array_equal(out, np.sort(x))
+    assert eng.last_report["pad_reused"] is warm
+    assert stages_of_one_call(trace) == SIM_STAGES  # nested in the call, on its thread
+    pads = [h[2] for h in trace.host if h[2].startswith(engine.SPAN_PAD)]
+    assert pads == ["sort_engine.pad"]
+
+
 def test_host_path_spans(tmp_path):
     eng = SortEngine(host_threshold=1000)
     x = keys()
