@@ -18,8 +18,10 @@ Sort — ``ohhc_sort_sim`` (jit/vmap simulated processors), ``ohhc_sort_host``
 
 2. **Dispatch** (``choose_plan``): stats × topology → execution path and
    method.  The full decision table is DESIGN.md §4; the shape is
-   *mesh → dist (hier > valiant > sampled > paper), huge or heavily skewed
-   → host (exact ragged buckets), else → sim*.
+   *mesh → dist (hier > valiant > sampled > paper), huge → host (exact
+   ragged buckets), large and skewed past the balanced capacity floor of
+   its method → host, else → sim* (skewed inputs with ``sampled``
+   splitters).
 
 3. **Capacity autotune** (``autotune_capacity``): instead of the fixed
    ``2·ceil(n/P)`` heuristic, capacity comes from the measured max bucket
@@ -369,6 +371,12 @@ class SortPlan:
     fault_slowdown: float | None = None
 
 
+def _capacity_floor(padded_n: int, num_buckets: int) -> int:
+    """The balanced floor of :func:`autotune_capacity`: the capacity every
+    input gets whose measured max bucket fraction stays near ``1/P``."""
+    return min(partition.default_capacity(padded_n, num_buckets), padded_n)
+
+
 def autotune_capacity(
     stats: InputStats,
     method: str,
@@ -393,7 +401,7 @@ def autotune_capacity(
     the measured need).
     """
     f_hat = stats.f_max_paper if method == "paper" else stats.f_max_sampled
-    base = min(partition.default_capacity(padded_n, num_buckets), padded_n)
+    base = _capacity_floor(padded_n, num_buckets)
     raw = math.ceil(f_hat * margin * padded_n)
     if raw <= base:
         return base
@@ -512,16 +520,20 @@ def choose_plan(
             "host", "paper", None, None,
             f"n={stats.n} ≥ host threshold: exact ragged buckets, no pad waste",
         )
-    if stats.skewed and stats.n > (1 << 16):
-        return SortPlan(
-            "host", "paper", None, None,
-            "large skewed input: dense (P, capacity) buffer would dwarf n",
-        )
     padded_n = partition.bucketed_length(stats.n)
     cap = autotune_capacity(stats, method, P, padded_n, margin=margin)
+    # A large skewed input stays on the device only while its planned
+    # (P, capacity) buffer is the one a uniform input of its size gets.
+    floor = _capacity_floor(padded_n, P)
+    if stats.skewed and stats.n > (1 << 16) and cap > floor:
+        return SortPlan(
+            "host", "paper", None, None,
+            f"large skewed input: {method} capacity {cap} is above the "
+            f"balanced floor {floor}, a dense (P, capacity) buffer would dwarf n",
+        )
     return SortPlan(
         "sim", method, cap, padded_n,
-        f"{stats.label} input on the jit path, capacity={cap}",
+        f"{stats.label} input on the jit path, capacity={cap}, balanced floor {floor}",
     )
 
 

@@ -44,6 +44,29 @@ def key_space_max(dtype) -> int:
     return int(np.iinfo(np.int32).max)
 
 
+def local_keys(rng: np.random.Generator, n: int, dtype=np.int32) -> np.ndarray:
+    """The paper's 'local' array of ``n`` keys of ``dtype``, drawn from ``rng``.
+
+    A tight gaussian cluster in the middle of the key space and a thin
+    uniform tail (``n/1000`` positions, at least 2) so that min/max span
+    the full range: the worst case for equal-width splitters, whose span
+    is huge while the mass is narrow.  The cluster width scales with the
+    key space; for very narrow dtypes (int8) it degenerates toward the
+    dupes class, which is the honest physical limit of "local" on a
+    127-value space.
+    """
+    dt = np.dtype(dtype)
+    vmax = key_space_max(dt)
+    center = vmax // 2
+    sigma = max(1.0, 1e5 * (vmax / np.iinfo(np.int32).max))
+    x = rng.normal(center, sigma, n).astype(np.int64)
+    if n:  # an empty array has no positions to draw the tail into
+        k = max(n // 1000, 2)
+        idx = rng.integers(0, n, k)
+        x[idx] = rng.integers(0, vmax, k, dtype=np.int64)
+    return np.clip(x, 0, vmax).astype(dt)
+
+
 def make_array(dist: str, n: int, seed: int = 0, dtype=np.int32) -> np.ndarray:
     """Generate one paper-grid input array, scaled to ``dtype``'s key space.
 
@@ -68,19 +91,7 @@ def make_array(dist: str, n: int, seed: int = 0, dtype=np.int32) -> np.ndarray:
         w = 1.0 / np.arange(1, 17)
         x = rng.choice(vals, size=n, p=w / w.sum())
     elif dist == "local":
-        # tight gaussian cluster in the middle of the key space + a thin
-        # uniform tail so min/max span the full range (worst case for
-        # equal-width splitters: the span is huge, the mass is narrow).
-        # The cluster width scales with the key space; for very narrow
-        # dtypes (int8) it degenerates toward the dupes class, which is the
-        # honest physical limit of "local" on a 127-value space.
-        center = vmax // 2
-        sigma = max(1.0, 1e5 * (vmax / np.iinfo(np.int32).max))
-        x = rng.normal(center, sigma, n).astype(np.int64)
-        if n:  # an empty array has no positions to draw the tail into
-            k = max(n // 1000, 2)
-            idx = rng.integers(0, n, k)
-            x[idx] = rng.integers(0, vmax, k, dtype=np.int64)
+        return local_keys(rng, n, dt)
     else:
         raise ValueError(f"unknown distribution {dist!r}")
     return np.clip(x, 0, vmax).astype(dt)

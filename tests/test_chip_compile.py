@@ -83,6 +83,20 @@ def test_sim_sort_compiles_for_v5e(one_chip):
     _fits_one_chip(compiled)
 
 
+def test_sim_sampled_sort_compiles_for_v5e(one_chip):
+    # skewed keys take sampled splitters: a device sample, its sort and the
+    # splitter search, then the chain the paper method shares
+    n = 1 << 16
+    eng = SortEngine()
+    plan = eng.plan(make_array("local", n, seed=0))
+    assert (plan.path, plan.method) == ("sim", "sampled")
+    fn = eng._get_sim_fn(n, plan.capacity, plan.method, np.int32, False)
+    compiled = fn.lower(
+        _spec((n,), jnp.int32, one_chip), _spec((), jnp.int32, one_chip)
+    ).compile()
+    _fits_one_chip(compiled)
+
+
 @pytest.mark.parametrize("dtype", [jnp.int32, jnp.float32])
 def test_row_sort_compiles_for_v5e(one_chip, dtype):
     # the default segment row backend (vmapped jnp.sort), as Sortd runs it

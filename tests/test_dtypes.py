@@ -3,6 +3,7 @@
 arithmetic across signed ranges, and ``sort_many`` bucketing for
 uint32/int8 — including the all-max/all-min sentinel-collision edges."""
 
+import hashlib
 import pathlib
 
 import jax.numpy as jnp
@@ -12,7 +13,7 @@ import pytest
 from repro.core import OHHCTopology, SortEngine, estimate_stats
 from repro.core.engine import _sim_fill, _sim_low
 from repro.core.ohhc_sort import ohhc_sort_host
-from repro.data.distributions import ALL_DISTRIBUTIONS, key_space_max, make_array
+from repro.data.distributions import ALL_DISTRIBUTIONS, key_space_max, local_keys, make_array
 
 pytestmark = pytest.mark.conformance
 
@@ -38,6 +39,39 @@ def test_make_array_int32_matches_historical_generator():
     rng = np.random.default_rng(42)
     ref = rng.integers(0, np.iinfo(np.int32).max, 1000, dtype=np.int64)
     np.testing.assert_array_equal(x, np.clip(ref, 0, np.iinfo(np.int32).max).astype(np.int32))
+
+
+# SHA-256 of make_array("local", 4099, seed=11, dtype) as generated before
+# the local branch moved into local_keys: the move must not change a byte.
+LOCAL_SHA256 = {
+    "int8": "f5800722a51ae146c279fc8b5294e2a2b3608eb8be5665b9dc13f2e9b953ba79",
+    "int16": "0c15a74af343c01f647c845510fa2de4d310b05ccda10146c05bbae83510946d",
+    "int32": "fc7fcdadad952fe5adb4764bc29c2c718aa2b9e5f29d591489e6b5c95fa43b10",
+    "int64": "7865113fa9dc94cb71c9657fae6a115d12a9b2aa266a20610baf3978d8b111e4",
+    "uint8": "edd975dd3467c7692384008eae86f039733819890dd8bb187c874a70e199c9f6",
+    "uint16": "8aa626e208060114a9a2da535817e3d2785a5ddaa69428939e44b5daee28b4ad",
+    "uint32": "1dd10c231c02a5b8296bef9c5b1b8a10e73fecdc2dfe4c07a201f4608b68fd70",
+    "uint64": "7865113fa9dc94cb71c9657fae6a115d12a9b2aa266a20610baf3978d8b111e4",
+    "float32": "73341da9fa81c1e019bf0d8a8383ef3025d549ecef23822a56a1c08c36012810",
+    "float64": "d96d2fa4b76ba4c2f825e35f7476eeb108a4071c0391265e6cd38e238bbdbd5f",
+}
+
+
+@pytest.mark.parametrize("dtype", list(LOCAL_SHA256))
+def test_make_array_local_is_unchanged(dtype):
+    x = make_array("local", 4099, seed=11, dtype=np.dtype(dtype))
+    assert hashlib.sha256(x.tobytes()).hexdigest() == LOCAL_SHA256[dtype]
+    y = local_keys(np.random.default_rng(11), 4099, np.dtype(dtype))
+    assert y.dtype == x.dtype and y.tobytes() == x.tobytes()
+
+
+@pytest.mark.parametrize("n", [0, 1, 2, 999, 1000])
+def test_local_keys_draw_from_the_callers_stream(n):
+    # make_array hands its seed to np.random.default_rng, which returns a
+    # Generator unaltered: both calls read one stream
+    a = local_keys(np.random.default_rng(5), n)
+    b = make_array("local", n, seed=np.random.default_rng(5))
+    assert a.dtype == np.int32 and a.size == n and a.tobytes() == b.tobytes()
 
 
 # ----------------------------------------------------------------- stats

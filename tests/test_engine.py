@@ -98,11 +98,34 @@ def test_policy_huge_goes_host():
     assert p.path == "host"
 
 
-def test_policy_large_skewed_goes_host():
-    # ragged host buckets are exact under any splitter, so the cheaper
-    # equal-width rule is always the host-path method
-    p = choose_plan(mk_stats(n=1 << 17, skew=9.0), TOPO)
-    assert (p.path, p.method) == ("host", "paper")
+@pytest.mark.parametrize(
+    "n, stats, want",
+    [
+        # sampled splitters balance a clustered input: the uniform floor
+        (1 << 17, dict(skew=9.0, f_max_sampled=0.03), ("sim", "sampled")),
+        (15_728_640, dict(skew=63.9, f_max_sampled=0.03), ("sim", "sampled")),
+        # one value holds a tenth of the keys: no splitter splits it, so the
+        # sampled capacity climbs above the floor
+        (1 << 17, dict(skew=9.0, f_max_sampled=0.1), ("host", "paper")),
+        # duplicate-dominated: the paper rule's hot bucket sizes the buffer
+        (1 << 17, dict(skew=12.0, dup_top_frac=0.4, f_max_paper=0.45), ("host", "paper")),
+        (15_728_640, dict(skew=12.0, dup_top_frac=0.4, f_max_paper=0.45), ("host", "paper")),
+    ],
+    ids=["clustered-2^17", "clustered-60mb", "heavy-value", "dupes-2^17", "dupes-60mb"],
+)
+def test_policy_large_skewed_input_by_its_planned_capacity(n, stats, want):
+    # a large skewed input runs on the device only at the capacity a uniform
+    # input of its size gets; else the host's ragged buckets, which are
+    # exact under any splitter, so the cheaper equal-width rule
+    p = choose_plan(mk_stats(n=n, **stats), TOPO)
+    assert (p.path, p.method) == want
+    floor = default_capacity(bucketed_length(n), TOPO.total_procs)
+    if p.path == "sim":
+        assert (p.capacity, p.padded_n) == (floor, bucketed_length(n))
+        assert f"capacity={floor}, balanced floor {floor}" in p.reason
+    else:
+        assert p.capacity is None
+        assert f"above the balanced floor {floor}" in p.reason
 
 
 def test_policy_mesh_dispatch():
@@ -221,6 +244,35 @@ def test_autotuned_capacity_property(n, seed, dist, method):
     out = eng.sort(x, plan=plan)
     np.testing.assert_array_equal(out, np.sort(x))
     assert eng.last_report["counts_sum"] == n
+
+
+@pytest.mark.parametrize("dtype", [np.int32, np.float32])
+@pytest.mark.parametrize("n", [1 << 17, 1 << 18])
+def test_large_local_arrays_sort_on_the_sim_sampled_path(n, dtype):
+    """The paper's local arrays above 2^16 run on the device with sampled
+    splitters at the uniform capacity floor: exact, no retry, the fullest
+    bucket near the mean."""
+    eng = SortEngine(TOPO)
+    x = make_array("local", n, seed=n + 19, dtype=dtype)
+    out = eng.sort(x)
+    np.testing.assert_array_equal(out, np.sort(x))
+    rep = eng.last_report
+    assert (rep["plan"].path, rep["plan"].method) == ("sim", "sampled")
+    assert rep["capacity_used"] == default_capacity(n, TOPO.total_procs)
+    assert rep["overflow_retries"] == 0
+    assert rep["counts"].sum() == n
+    assert TOPO.total_procs * rep["counts"].max() / n < 1.5
+
+
+def test_host_report_counts_show_the_hot_bucket():
+    eng = SortEngine(TOPO)
+    x = make_array("dupes", 1 << 17, seed=23)
+    out = eng.sort(x)
+    np.testing.assert_array_equal(out, np.sort(x))
+    rep = eng.last_report
+    assert rep["plan"].path == "host"
+    assert rep["counts"].sum() == x.size
+    assert rep["counts"].max() / x.size > 0.25  # the dominant value's bucket
 
 
 # --------------------------------------------- bucket-id precision (int)
