@@ -88,6 +88,26 @@ SEGMENT_BITONIC_MAX = 1 << 13
 SIM_MAX_N = 1 << 24
 HOST_THRESHOLD = SIM_MAX_N + 1
 
+# The sim path copies a result bucket of at least two pieces' bytes to the
+# host as equal pieces, one per D2H_PIECE_BYTES, at most D2H_MAX_PIECES,
+# each copied and joined by a thread of its own (``d2h_pieces``).  One
+# thread copying a 64 MiB result untiles it into fresh pages at ≈0.8 GB/s;
+# on a TPU v5e host the 2^24 int32 bucket's D2H and join took 83 ms as one
+# piece, 84 as 2, 36 as 4, 27 as 8 and 26 as 16 (medians of 15).
+D2H_PIECE_BYTES = 8 << 20
+D2H_MAX_PIECES = 8
+
+
+def d2h_pieces(padded_n: int, dtype) -> int:
+    """The pieces the sim path copies a ``padded_n`` bucket of ``dtype`` to
+    the host in: one per :data:`D2H_PIECE_BYTES`, at most
+    :data:`D2H_MAX_PIECES`, a power of two that divides ``padded_n``; 1 for
+    a bucket under two pieces' bytes."""
+    k = min(D2H_MAX_PIECES, padded_n * np.dtype(dtype).itemsize // D2H_PIECE_BYTES)
+    if k < 2:
+        return 1
+    return math.gcd(1 << (k.bit_length() - 1), padded_n)
+
 # Profiler spans of ``SortEngine.sort`` (DESIGN.md §4).
 SPAN_SORT = "sort_engine.sort"
 SPAN_PLAN = "sort_engine.plan"
@@ -104,11 +124,12 @@ every path; the stages nest inside it on the calling thread:
 of a staging buffer from the engine's pool, the copy of the keys and the
 zeroing of the tail; dist: the shard-divisibility pad), ``SPAN_H2D``, one
 ``SPAN_EXECUTE`` per attempt (dispatch plus the counts sync that waits
-for it, so an overflow retry shows as a second span), ``SPAN_D2H``,
-``SPAN_UNPACK`` (dist) and ``SPAN_HOST_SORT`` (host path).  They record
-only while a profiler runs, on its clock.  No span adds a sync: each
-measures what the host spends in its stage, and the trace's device lines
-show the rest.  Every executable the engine builds has a stable module
+for it, so an overflow retry shows as a second span), ``SPAN_D2H`` (sim:
+the device result to the caller's array, the pieces' copies and their
+join included), ``SPAN_UNPACK`` (dist) and ``SPAN_HOST_SORT`` (host
+path).  They record only while a profiler runs, on its clock.  No span
+adds a sync: each measures what the host spends in its stage, and the
+trace's device lines show the rest.  Every executable the engine builds has a stable module
 name (``jit_sim_sort``, ``jit_row_sort``, ``jit_pairs_sort``,
 ``jit_sim_topk``, ``jit_dist_sort``)."""
 
@@ -116,21 +137,23 @@ name (``jit_sim_sort``, ``jit_row_sort``, ``jit_pairs_sort``,
 def join_prefixes(
     parts: Sequence[np.ndarray], counts: Sequence[int], pool: Executor | None
 ) -> tuple[np.ndarray, int]:
-    """``np.concatenate([p[:c] for p, c in zip(parts, counts)])`` into one
-    fresh array, and the number of threads that wrote it.
+    """``np.concatenate([np.asarray(p)[:c] for p, c in zip(parts, counts)])``
+    into one fresh array, and the number of threads that wrote it.
 
     Writing fresh memory is bound by first-touch page faults, not by
     bandwidth, so with a ``pool`` every part gets a writer of its own,
     copying into its own slice of the output at once with the others
     (numpy releases the GIL for a same-dtype copy).  With one part, or no
-    pool, the calling thread copies.  ``parts`` share a dtype.
+    pool, the calling thread copies.  ``parts`` share a dtype; a part may
+    be a device array, which its writer copies to the host first (jax
+    releases the GIL for that copy too).
     """
     counts = [int(c) for c in counts]
     offsets = np.cumsum([0, *counts])
     out = np.empty(int(offsets[-1]), parts[0].dtype)
 
     def write(i: int) -> None:
-        out[offsets[i] : offsets[i + 1]] = parts[i][: counts[i]]
+        out[offsets[i] : offsets[i + 1]] = np.asarray(parts[i])[: counts[i]]
 
     if pool is None or len(parts) == 1:
         for i in range(len(parts)):
@@ -694,6 +717,16 @@ class SortEngine:
     tail, so the executable reads the same bytes as from a fresh buffer.
     Padding on the device instead would take an input of shape ``(n,)``:
     one executable per distinct ``n``, not per bucket.
+
+    ``sort`` on the sim path returns a fresh, writable array of exactly
+    ``n`` keys.  A bucket of at least two pieces' bytes
+    (:func:`d2h_pieces`: 8 MiB a piece, at most 8) comes back from its
+    executable as equal pieces; each piece that holds a valid key gets a
+    thread of the engine's join pool, which copies it to the host and
+    writes its keys into its own slice of the result (:func:`join_prefixes`).
+    A smaller bucket is one piece, copied by the calling thread, and the
+    result is a read-only view of that copy.  ``last_report["d2h_pieces"]``
+    counts the pieces copied.
     """
 
     def __init__(
@@ -721,7 +754,8 @@ class SortEngine:
         self._fault_info: dict[str, dict] = {}
         self.trace_count = 0  # incremented once per actual jit trace
         self.last_report: dict | None = None
-        # The dist path's join writers, one per shard, made on first use.
+        # The join writers of the dist path's shards and the sim path's D2H
+        # pieces, one per part, made on first use.
         self._join_pool: ThreadPoolExecutor | None = None
         self._join_pool_lock = threading.Lock()
         # The sim path's idle staging buffers, one per (padded_n, dtype).
@@ -887,7 +921,11 @@ class SortEngine:
 
     # -------------------------------------------------------------- jit cache
     def _get_sim_fn(self, padded_n: int, capacity: int, method: str, dtype, batched: bool):
-        key = ("batch" if batched else "sim", padded_n, capacity, method, str(dtype))
+        """The jitted sim sort of one bucket: ``(out, counts)``, where the
+        non-batched ``out`` is a tuple of :func:`d2h_pieces` equal pieces
+        when that is more than one."""
+        pieces = 1 if batched else d2h_pieces(padded_n, dtype)
+        key = ("batch" if batched else "sim", padded_n, capacity, method, str(dtype), pieces)
         fn = self._fn_cache.get(key)
         if fn is not None:
             return fn
@@ -905,7 +943,7 @@ class SortEngine:
 
         def sim_sort(x_pad, n_valid):
             self.trace_count += 1  # runs at trace time only
-            return _sim_sort_padded(
+            out, counts = _sim_sort_padded(
                 x_pad,
                 n_valid,
                 P=self.topo.total_procs,
@@ -913,6 +951,9 @@ class SortEngine:
                 method=method,
                 sample_size=min(self.sample_size, padded_n),
             )
+            if pieces > 1:  # each piece copies to the host on its own thread
+                out = tuple(jnp.split(out, pieces))
+            return out, counts
 
         traced = row_sort if method == "bitonic" else sim_sort
         fn = jax.jit(jax.vmap(traced) if batched else traced)
@@ -1006,13 +1047,20 @@ class SortEngine:
             capacity = _grow_capacity(capacity, padded_n)
             retries += 1
         with jax.profiler.TraceAnnotation(SPAN_D2H):
-            out = np.asarray(out)[:n]
+            if isinstance(out, tuple):
+                # Pieces past n hold no valid key and are not copied.
+                size = out[0].size
+                live = -(-n // size)
+                sizes = [size] * (live - 1) + [n - (live - 1) * size]
+                out, copied = join_prefixes(out[:live], sizes, self._join_writers(live))
+            else:
+                out, copied = np.asarray(out)[:n], 1
             counts = np.asarray(counts)
         self._release_pad(x_pad)
         self.last_report = {
             "plan": plan, "n": n, "stats": stats, "capacity_used": capacity,
             "counts_sum": got, "overflow_retries": retries, "counts": counts,
-            "pad_reused": reused,
+            "pad_reused": reused, "d2h_pieces": copied,
         }
         return out
 
@@ -1660,18 +1708,21 @@ class SortEngine:
         }
         return out[:n]
 
-    def _join_writers(self, num_shards: int) -> ThreadPoolExecutor | None:
-        """The pool that joins the dist path's shards on the host, one
-        worker per shard, shared by concurrent callers; ``None`` for one
-        shard."""
-        if num_shards == 1:
+    def _join_writers(self, num_parts: int) -> ThreadPoolExecutor | None:
+        """The pool that joins ``num_parts`` parts on the host (the dist
+        path's shards, the sim path's D2H pieces), shared by both paths and
+        by concurrent callers; ``None`` for one part.  It is as wide as the
+        larger of the two paths' needs, whichever makes it: one worker per
+        device of the mesh, or :data:`D2H_MAX_PIECES`."""
+        if num_parts == 1:
             return None
         with self._join_pool_lock:
             if self._join_pool is None:
-                # Imported here: a process with no mesh engine never loads it.
+                # Imported here: a process that never joins parts never loads it.
                 from concurrent.futures import ThreadPoolExecutor
 
+                devices = int(self.mesh.devices.size) if self.mesh is not None else 1
                 self._join_pool = ThreadPoolExecutor(
-                    num_shards, thread_name_prefix="sort_join"
+                    max(D2H_MAX_PIECES, devices), thread_name_prefix="sort_join"
                 )
             return self._join_pool

@@ -97,6 +97,26 @@ def test_sim_sampled_sort_compiles_for_v5e(one_chip):
     _fits_one_chip(compiled)
 
 
+def test_split_sim_sort_compiles_for_v5e(one_chip, monkeypatch):
+    # 32-KiB pieces: the 2^16 int32 bucket comes back as the most pieces,
+    # as the 2^24 bucket does at the real piece size
+    from repro.core import engine
+
+    monkeypatch.setattr(engine, "D2H_PIECE_BYTES", 32 << 10)
+    n = 1 << 16
+    eng = SortEngine()
+    plan = eng.plan(make_array("random", n, seed=0))
+    assert plan.path == "sim"
+    fn = eng._get_sim_fn(n, plan.capacity, plan.method, np.int32, False)
+    args = _spec((n,), jnp.int32, one_chip), _spec((), jnp.int32, one_chip)
+    lowered = fn.lower(*args)
+    assert lowered.as_text().splitlines()[0].startswith("module @jit_sim_sort ")
+    pieces, _ = jax.eval_shape(fn, *args)
+    k = engine.D2H_MAX_PIECES
+    assert [p.shape for p in pieces] == [(n // k,)] * k
+    _fits_one_chip(lowered.compile())
+
+
 @pytest.mark.parametrize("dtype", [jnp.int32, jnp.float32])
 def test_row_sort_compiles_for_v5e(one_chip, dtype):
     # the default segment row backend (vmapped jnp.sort), as Sortd runs it

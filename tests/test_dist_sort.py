@@ -230,8 +230,24 @@ def test_join_prefixes_matches_concatenate(join_pool, counts, dtype):
     assert writers == len(parts)
 
 
+def test_join_prefixes_reads_device_parts(join_pool):
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(11)
+    host = [rng.integers(0, 1 << 30, 512, dtype=np.int32) for _ in range(4)]
+    counts = [512, 512, 300, 0]
+    got, writers = join_prefixes([jnp.asarray(p) for p in host], counts, join_pool)
+    want = np.concatenate([p[:c] for p, c in zip(host, counts)])
+    assert got.dtype == want.dtype and got.tobytes() == want.tobytes()
+    assert got.flags.writeable and got.flags.owndata
+    assert writers == 4
+
+
 class _Unreadable:
     dtype = np.dtype(np.int32)
+
+    def __array__(self, *args, **kwargs):  # a writer reads its part to the host
+        raise RuntimeError("unreadable shard")
 
     def __getitem__(self, key):
         raise RuntimeError("unreadable shard")

@@ -3,14 +3,17 @@ jit cache (no recompiles within a shape bucket), batched entry points."""
 
 import dataclasses
 import os
+import subprocess
 import sys
 import threading
 from concurrent.futures import ThreadPoolExecutor
+from pathlib import Path
 
 import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
 
+from repro.core import engine as engine_module
 from repro.core import (
     InputStats,
     OHHCTopology,
@@ -788,3 +791,151 @@ def test_a_failed_call_drops_its_pad_buffer(op, rng):
     delattr(eng, getter)
     assert_exact(run(eng, x), answer(x))
     assert eng.last_report["pad_reused"] is False
+
+
+# ----------------------------------------------------------- split sim D2H
+SPLIT_BUCKET = 4096
+SPLIT_PIECE = SPLIT_BUCKET // engine_module.D2H_MAX_PIECES  # 512 keys
+
+
+@pytest.fixture
+def split_d2h(monkeypatch):
+    """Pieces of 2 KiB: the 2^12 bucket of 4-byte keys comes back from its
+    executable as the most pieces, 8 of 512 keys."""
+    monkeypatch.setattr(engine_module, "D2H_PIECE_BYTES", SPLIT_PIECE * 4)
+    assert engine_module.d2h_pieces(SPLIT_BUCKET, np.int32) == engine_module.D2H_MAX_PIECES
+
+
+@pytest.mark.parametrize(
+    "padded_n, dtype, want",
+    [(1 << 21, np.int32, 1), (1 << 22, np.int32, 2), (1 << 23, np.int32, 4),
+     (1 << 24, np.int32, 8), (1 << 25, np.int32, 8), (1 << 22, np.int16, 1),
+     (1 << 21, np.float64, 2), (3 << 22, np.int32, 4), (1 << 10, np.int32, 1)],
+)
+def test_d2h_pieces_by_the_buckets_bytes(padded_n, dtype, want):
+    k = engine_module.d2h_pieces(padded_n, dtype)
+    assert k == want and padded_n % k == 0
+
+
+def assert_owned_result(out, n):
+    """A fresh, writable, C-contiguous array of exactly ``n`` keys that no
+    device buffer's host copy backs."""
+    assert out.shape == (n,)
+    assert out.flags.c_contiguous and out.flags.writeable
+    assert out.flags.owndata and out.base is None
+
+
+@pytest.mark.parametrize("dtype", POOL_DTYPES)
+@pytest.mark.parametrize(
+    "n, copied",
+    [(SPLIT_BUCKET, 8), (SPLIT_BUCKET - 1, 8), (5 * SPLIT_PIECE, 5),
+     (SPLIT_PIECE - 212, 1), (SPLIT_BUCKET // 2 + 1, 5)],
+    ids=["padded_n", "padded_n-1", "piece_boundary", "first_piece", "past_lower_bucket"],
+)
+def test_split_sim_d2h_is_exact(split_d2h, n, copied, dtype, rng):
+    eng = SortEngine(TOPO)
+    x = random_keys(rng, n, dtype)
+    plan = SortPlan("sim", "paper", None, SPLIT_BUCKET, "split forced")
+    out = eng.sort(x, plan=plan)
+    assert_exact(out, np.sort(x))
+    assert_owned_result(out, n)
+    rep = eng.last_report
+    assert rep["plan"].padded_n == SPLIT_BUCKET
+    # the pieces past n hold no valid key and are not copied
+    assert rep["d2h_pieces"] == copied == -(-n // SPLIT_PIECE)
+    # one executable per capacity tried, each returning 8 pieces
+    assert {(key[0], key[-1]) for key in eng._fn_cache} == {("sim", 8)}
+
+
+def test_split_sim_d2h_planned_bucket_and_retry(split_d2h, rng):
+    eng = SortEngine(TOPO)
+    x = random_keys(rng, SPLIT_BUCKET - 100, np.int32)
+    out = eng.sort(x)
+    assert_exact(out, np.sort(x))
+    assert_owned_result(out, x.size)
+    assert eng.last_report["plan"].padded_n == SPLIT_BUCKET
+    assert eng.last_report["d2h_pieces"] == 8
+    # an overflow retry copies the pieces of the attempt that succeeded
+    out = eng.sort(x, plan=SortPlan("sim", "paper", 64, None, "capacity forced too small"))
+    assert_exact(out, np.sort(x))
+    assert eng.last_report["overflow_retries"] >= 1 and eng.last_report["d2h_pieces"] == 8
+
+
+def test_sim_d2h_below_the_split_size_is_one_piece(rng):
+    eng = SortEngine(TOPO)
+    x = random_keys(rng, 5000, np.int32)
+    out = eng.sort(x)
+    assert_exact(out, np.sort(x))
+    assert eng.last_report["d2h_pieces"] == 1
+    assert out.shape == (5000,) and not out.flags.writeable  # a view of the host copy
+    (key,) = eng._fn_cache
+    assert key[-1] == 1
+
+
+def test_split_sim_d2h_eight_threads_on_one_engine(split_d2h):
+    eng = SortEngine(TOPO)
+    workers = 8
+    start = threading.Barrier(workers)
+
+    def caller(seed):
+        rng = np.random.default_rng(seed)
+        start.wait()
+        for i in range(6):
+            x = random_keys(rng, SPLIT_BUCKET - 97 * i * seed % 1500, np.int32)
+            out = eng.sort(x)
+            assert_exact(out, np.sort(x))
+            assert_owned_result(out, x.size)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        with ThreadPoolExecutor(workers) as pool:
+            for f in [pool.submit(caller, seed) for seed in range(1, workers + 1)]:
+                f.result(timeout=300)
+    finally:
+        sys.setswitchinterval(interval)
+    assert eng._join_pool._max_workers == engine_module.D2H_MAX_PIECES
+
+
+SHARED_POOL_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import numpy as np
+from repro import compat
+from repro.core import SortEngine, engine
+from repro.core.engine import SortPlan
+
+engine.D2H_PIECE_BYTES = 2048  # the 2^12 int32 bucket comes back as 8 pieces
+mesh = compat.make_mesh((4,), ("data",))
+rng = np.random.default_rng(3)
+sim = SortPlan("sim", "paper", None, None, "forced onto one device")
+
+def exact(eng, x, **kw):
+    got = eng.sort(x, **kw)
+    assert got.tobytes() == np.sort(x).tobytes()
+    return eng.last_report
+
+for sim_first in (True, False):
+    eng = SortEngine(mesh=mesh)
+    calls = [("sim", 4000), ("dist", 16384)]
+    for path, n in (calls if sim_first else calls[::-1]):
+        x = rng.integers(0, 2**31 - 1, n, dtype=np.int32)
+        rep = exact(eng, x, plan=sim if path == "sim" else None)
+        assert rep["plan"].path == path, rep
+        if path == "sim":
+            assert rep["d2h_pieces"] == 8, rep
+        else:
+            assert rep["unpack_writers"] == 4, rep
+    assert eng._join_pool._max_workers == 8
+print("SHARED_POOL_OK")
+"""
+
+
+def test_dist_and_split_sim_share_one_join_pool_on_4_devices():
+    r = subprocess.run(
+        [sys.executable, "-c", SHARED_POOL_SCRIPT],
+        capture_output=True, text=True, timeout=600,
+        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin:/usr/local/bin", "JAX_PLATFORMS": "cpu"},
+        cwd=Path(__file__).resolve().parents[1],
+    )
+    assert "SHARED_POOL_OK" in r.stdout, r.stderr[-3000:]

@@ -85,6 +85,20 @@ def test_sim_pad_is_one_plain_span_with_a_first_or_reused_buffer(tmp_path, warm)
     assert pads == ["sort_engine.pad"]
 
 
+def test_split_d2h_is_the_last_sim_stage_and_joins_inside_it(tmp_path, monkeypatch):
+    # 2-KiB pieces: the 8,192-slot bucket of 5,000 keys comes back as 8
+    # pieces, of which the 5 that hold keys are copied and joined
+    monkeypatch.setattr(engine, "D2H_PIECE_BYTES", 2048)
+    eng = SortEngine()
+    x = keys()
+    out, trace = profiled(tmp_path, lambda: eng.sort(x))
+    assert np.array_equal(out, np.sort(x))
+    assert eng.last_report["d2h_pieces"] == 5
+    # the join workers record no span: every stage is on the calling thread
+    assert stages_of_one_call(trace) == SIM_STAGES
+    assert not [h for h in trace.host if h[2] == engine.SPAN_UNPACK]
+
+
 def test_host_path_spans(tmp_path):
     eng = SortEngine(host_threshold=1000)
     x = keys()
